@@ -67,6 +67,7 @@ from typing import Dict, Tuple
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core import (
     AnalyzerConfig,
     GAConfig,
@@ -492,134 +493,122 @@ def bench_simspeed(args) -> None:
     #     compiled_speedup_full_scenario; the crossover leg below (6c)
     #     times all three engines on one workload and carries the gated
     #     compiled_speedup (compiled vs the numpy lock-step tier).
-    try:
-        import jax as _jax  # noqa: F401
-        _have_jax = True
-    except Exception:
-        _have_jax = False
-    if _have_jax:
-        import repro.core.batchsim_compiled as _bsc
-        from repro.core import COMPILED_ABS_TOL, COMPILED_REL_TOL
+    import repro.core.batchsim_compiled as _bsc
+    from repro.core import COMPILED_ABS_TOL, COMPILED_REL_TOL
 
-        an_c = make_analyzer("fast", "bisect")
-        an_c.cfg.batch_engine = "compiled"
-        cold_s, _ = time_population(
-            lambda a: a.objectives_batch(generation), an_c)
-        an_c2 = make_analyzer("fast", "bisect")
-        an_c2.cfg.batch_engine = "compiled"
-        comp_s, objs_comp = time_population(
-            lambda a: a.objectives_batch(generation), an_c2)
-        assert _bsc.last_stats.get("fallback") is False, _bsc.last_stats
-        comp_diff = 0.0
-        for row_a, row_b in zip(objs_loop, objs_comp):
-            for x, y in zip(row_a, row_b):
-                if math.isinf(x) or math.isinf(y):
-                    assert math.isinf(x) and math.isinf(y), "inf mismatch"
-                    continue
-                comp_diff = max(comp_diff, abs(x - y))
-                assert abs(x - y) <= (
-                    COMPILED_ABS_TOL
-                    + COMPILED_REL_TOL * max(abs(x), abs(y))
-                ), "compiled tolerance violated"
-        comp_us = comp_s / n * 1e6
-        comp_speedup = per_us / comp_us
-        emit("simspeed.pop_eval_batch_compiled", comp_us,
-             f"jitted while_loop;speedup=x{comp_speedup:.2f};"
-             f"max_diff={comp_diff:.3e};compile_s={cold_s - comp_s:.2f}")
-        record["eval_us_batch_compiled"] = comp_us
-        record["compiled_speedup_full_scenario"] = comp_speedup
-        record["compiled_max_diff"] = comp_diff
-        record["compiled_cold_compile_s"] = cold_s - comp_s
-        record["eval_us_batch"] = min(best_us, comp_us)
+    an_c = make_analyzer("fast", "bisect")
+    an_c.cfg.batch_engine = "compiled"
+    cold_s, _ = time_population(
+        lambda a: a.objectives_batch(generation), an_c)
+    an_c2 = make_analyzer("fast", "bisect")
+    an_c2.cfg.batch_engine = "compiled"
+    comp_s, objs_comp = time_population(
+        lambda a: a.objectives_batch(generation), an_c2)
+    assert _bsc.last_stats.get("fallback") is False, _bsc.last_stats
+    comp_diff = 0.0
+    for row_a, row_b in zip(objs_loop, objs_comp):
+        for x, y in zip(row_a, row_b):
+            if math.isinf(x) or math.isinf(y):
+                assert math.isinf(x) and math.isinf(y), "inf mismatch"
+                continue
+            comp_diff = max(comp_diff, abs(x - y))
+            assert abs(x - y) <= (
+                COMPILED_ABS_TOL
+                + COMPILED_REL_TOL * max(abs(x), abs(y))
+            ), "compiled tolerance violated"
+    comp_us = comp_s / n * 1e6
+    comp_speedup = per_us / comp_us
+    emit("simspeed.pop_eval_batch_compiled", comp_us,
+         f"jitted while_loop;speedup=x{comp_speedup:.2f};"
+         f"max_diff={comp_diff:.3e};compile_s={cold_s - comp_s:.2f}")
+    record["eval_us_batch_compiled"] = comp_us
+    record["compiled_speedup_full_scenario"] = comp_speedup
+    record["compiled_max_diff"] = comp_diff
+    record["compiled_cold_compile_s"] = cold_s - comp_s
+    record["eval_us_batch"] = min(best_us, comp_us)
 
-        # 6c) compiled crossover leg: a compact 2-group scenario at GA
-        #     width (80 lanes, measured noise + dispatch, 20 requests),
-        #     timed through all three batch-capable paths on identical
-        #     lanes. The gated compiled_speedup is compiled vs the numpy
-        #     lock-step tier it replaces on the batch path (>1 everywhere
-        #     measured, ~2.5-3x here). The scalar-loop comparison is
-        #     recorded separately as compiled_speedup_vs_scalar and is < 1
-        #     on this CPU: FastSimulator handles an event in ~0.75 µs of
-        #     python while the compiled core's masked full-width iteration
-        #     has a ~2 µs/lane floor at ~1.5 events per iteration — which
-        #     is the measured crossover, and why the scalar loop (not any
-        #     batch tier) remains the default CPU evaluation path.
-        from repro.core import (
-            BatchLane,
-            BatchSimulator,
-            FastSimulator,
-            NoiseModel,
-            SolutionFactory,
-            build_spec,
-            chain_graph,
-        )
-        from repro.core.batchsim_compiled import run_batch_compiled
+    # 6c) compiled crossover leg: a compact 2-group scenario at GA
+    #     width (80 lanes, measured noise + dispatch, 20 requests),
+    #     timed through all three batch-capable paths on identical
+    #     lanes. The gated compiled_speedup is compiled vs the numpy
+    #     lock-step tier it replaces on the batch path (>1 everywhere
+    #     measured, ~2.5-3x here). The scalar-loop comparison is
+    #     recorded separately as compiled_speedup_vs_scalar and is < 1
+    #     on this CPU: FastSimulator handles an event in ~0.75 µs of
+    #     python while the compiled core's masked full-width iteration
+    #     has a ~2 µs/lane floor at ~1.5 events per iteration — which
+    #     is the measured crossover, and why the scalar loop (not any
+    #     batch tier) remains the default CPU evaluation path.
+    from repro.core import (
+        BatchLane,
+        BatchSimulator,
+        FastSimulator,
+        NoiseModel,
+        SolutionFactory,
+        build_spec,
+        chain_graph,
+    )
+    from repro.core.batchsim_compiled import run_batch_compiled
 
-        procs_x, prof_x = _profiler()
-        nets_x = [
-            chain_graph("m0", [("conv", 6e6, 2500, 7500)] * 3),
-            chain_graph("m1", [("conv", 9e6, 3000, 9000)] * 4),
-            chain_graph("m2", [("fc", 4e6, 2000, 5000)] * 3),
-            chain_graph("m3", [("conv", 7e6, 2800, 8000)] * 3),
-        ]
-        groups_x = [[0, 1], [2, 3]]
-        periods_x = (0.033, 0.05)
-        fac_x = SolutionFactory(nets_x, num_processors=len(procs_x),
-                                rng=_random.Random(9), cut_prob=0.3)
-        lanes_x = []
-        for i in range(80):
-            spec_x = build_spec(decode_solution(fac_x.random_solution(),
-                                                nets_x),
-                                procs_x, prof_x, PAPER_COMM_MODEL)
-            lanes_x.append(BatchLane(
-                spec=spec_x, periods=periods_x, num_requests=20,
-                noise=NoiseModel(seed=i), dispatch_overhead=150e-6))
-        run_batch_compiled(lanes_x, groups_x, procs_x)  # pay the compile
-        gc.collect()
-        t0 = time.perf_counter()
-        res_x = run_batch_compiled(lanes_x, groups_x, procs_x)
-        comp_x_s = time.perf_counter() - t0
-        assert res_x is not None, _bsc.last_stats
-        assert _bsc.last_stats.get("fallback") is False, _bsc.last_stats
-        t0 = time.perf_counter()
-        fast_x = [
-            FastSimulator(ln.spec, groups=groups_x, periods=ln.periods,
-                          num_requests=ln.num_requests, noise=ln.noise,
-                          dispatch_overhead=ln.dispatch_overhead).run()
-            for ln in lanes_x
-        ]
-        scal_x_s = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        BatchSimulator(lanes_x, groups_x, procs_x).run()
-        np_x_s = time.perf_counter() - t0
-        diff_x = 0.0
-        for i, fr in enumerate(fast_x):
-            for a, b in zip([q.makespan for q in fr.requests],
-                            [q.makespan for q in res_x.result(i).requests]):
-                if math.isinf(a) or math.isinf(b):
-                    assert math.isinf(a) and math.isinf(b), "inf mismatch"
-                    continue
-                diff_x = max(diff_x, abs(a - b))
-                assert abs(a - b) <= (
-                    COMPILED_ABS_TOL + COMPILED_REL_TOL * max(abs(a), abs(b))
-                ), "compiled tolerance violated"
-        emit("simspeed.compiled_crossover", comp_x_s / 80 * 1e6,
-             f"compact 2-group scenario;scalar_us="
-             f"{scal_x_s / 80 * 1e6:.0f};numpy_us={np_x_s / 80 * 1e6:.0f};"
-             f"vs_numpy=x{np_x_s / comp_x_s:.2f};"
-             f"vs_scalar=x{scal_x_s / comp_x_s:.2f};"
-             f"max_diff={diff_x:.3e}")
-        record["compiled_speedup"] = np_x_s / comp_x_s
-        record["compiled_speedup_vs_scalar"] = scal_x_s / comp_x_s
-        record["compiled_crossover_us_scalar"] = scal_x_s / 80 * 1e6
-        record["compiled_crossover_us_compiled"] = comp_x_s / 80 * 1e6
-        record["compiled_crossover_us_numpy"] = np_x_s / 80 * 1e6
-    else:
-        emit("simspeed.pop_eval_batch_compiled", 0.0, "jax unavailable")
-        record["eval_us_batch_compiled"] = None
-        record["compiled_speedup"] = None
-        record["compiled_speedup_full_scenario"] = None
-        record["compiled_max_diff"] = None
+    procs_x, prof_x = _profiler()
+    nets_x = [
+        chain_graph("m0", [("conv", 6e6, 2500, 7500)] * 3),
+        chain_graph("m1", [("conv", 9e6, 3000, 9000)] * 4),
+        chain_graph("m2", [("fc", 4e6, 2000, 5000)] * 3),
+        chain_graph("m3", [("conv", 7e6, 2800, 8000)] * 3),
+    ]
+    groups_x = [[0, 1], [2, 3]]
+    periods_x = (0.033, 0.05)
+    fac_x = SolutionFactory(nets_x, num_processors=len(procs_x),
+                            rng=_random.Random(9), cut_prob=0.3)
+    lanes_x = []
+    for i in range(80):
+        spec_x = build_spec(decode_solution(fac_x.random_solution(),
+                                            nets_x),
+                            procs_x, prof_x, PAPER_COMM_MODEL)
+        lanes_x.append(BatchLane(
+            spec=spec_x, periods=periods_x, num_requests=20,
+            noise=NoiseModel(seed=i), dispatch_overhead=150e-6))
+    run_batch_compiled(lanes_x, groups_x, procs_x)  # pay the compile
+    gc.collect()
+    t0 = time.perf_counter()
+    res_x = run_batch_compiled(lanes_x, groups_x, procs_x)
+    comp_x_s = time.perf_counter() - t0
+    assert res_x is not None, _bsc.last_stats
+    assert _bsc.last_stats.get("fallback") is False, _bsc.last_stats
+    t0 = time.perf_counter()
+    fast_x = [
+        FastSimulator(ln.spec, groups=groups_x, periods=ln.periods,
+                      num_requests=ln.num_requests, noise=ln.noise,
+                      dispatch_overhead=ln.dispatch_overhead).run()
+        for ln in lanes_x
+    ]
+    scal_x_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    BatchSimulator(lanes_x, groups_x, procs_x).run()
+    np_x_s = time.perf_counter() - t0
+    diff_x = 0.0
+    for i, fr in enumerate(fast_x):
+        for a, b in zip([q.makespan for q in fr.requests],
+                        [q.makespan for q in res_x.result(i).requests]):
+            if math.isinf(a) or math.isinf(b):
+                assert math.isinf(a) and math.isinf(b), "inf mismatch"
+                continue
+            diff_x = max(diff_x, abs(a - b))
+            assert abs(a - b) <= (
+                COMPILED_ABS_TOL + COMPILED_REL_TOL * max(abs(a), abs(b))
+            ), "compiled tolerance violated"
+    emit("simspeed.compiled_crossover", comp_x_s / 80 * 1e6,
+         f"compact 2-group scenario;scalar_us="
+         f"{scal_x_s / 80 * 1e6:.0f};numpy_us={np_x_s / 80 * 1e6:.0f};"
+         f"vs_numpy=x{np_x_s / comp_x_s:.2f};"
+         f"vs_scalar=x{scal_x_s / comp_x_s:.2f};"
+         f"max_diff={diff_x:.3e}")
+    record["compiled_speedup"] = np_x_s / comp_x_s
+    record["compiled_speedup_vs_scalar"] = scal_x_s / comp_x_s
+    record["compiled_crossover_us_scalar"] = scal_x_s / 80 * 1e6
+    record["compiled_crossover_us_compiled"] = comp_x_s / 80 * 1e6
+    record["compiled_crossover_us_numpy"] = np_x_s / 80 * 1e6
 
     # batched population α*-search over a candidate set (Pareto-front shape)
     sat_cands = parents[:8]
@@ -1227,6 +1216,7 @@ def main() -> None:
         ap.error(f"conflicting sections: positional {args.section!r} "
                  f"vs --only {args.only!r}")
     selected = args.section or args.only
+    use_compile_cache()
     print("name,us_per_call,derived")
     for name, fn in SECTIONS.items():
         if selected and name != selected:

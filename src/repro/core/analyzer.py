@@ -24,7 +24,7 @@ if TYPE_CHECKING:  # typing-only: core must not import these at runtime
 
 from .arrivals import ArrivalSpec
 from .baselines import best_mapping_solutions, npu_only_solution
-from .batchsim import BatchLane, batch_objectives, run_batch
+from .batchsim import BatchLane, batch_objectives, run_batch, shard_pool
 from .chromosome import Solution, SolutionFactory, decode_solution
 from .comm import PiecewiseLinearCommModel
 from .fastsim import FastSimSpec, FastSimulator, SpecBuilder
@@ -172,9 +172,7 @@ class StaticAnalyzer:
     # -- batch plumbing ------------------------------------------------------
     def _pool(self) -> Optional[object]:
         if self.cfg.batch_workers > 1 and self._batch_pool is None:
-            from concurrent.futures import ProcessPoolExecutor
-            self._batch_pool = ProcessPoolExecutor(
-                max_workers=self.cfg.batch_workers)
+            self._batch_pool = shard_pool(self.cfg.batch_workers)
         return self._batch_pool
 
     def close(self) -> None:

@@ -56,8 +56,10 @@ path therefore stays the default.
 """
 from __future__ import annotations
 
+import logging
 import math
 import random
+from collections import Counter
 from dataclasses import dataclass
 from typing import List, Optional, Sequence, Tuple
 
@@ -754,6 +756,17 @@ def batch_objectives(
 SHARD_MIN_LANES = 256
 
 
+def shard_pool(workers: int) -> object:
+    """A process pool for :func:`run_batch` shards. Its workers are
+    spawned, not forked: a forked child would share the state of a parent
+    that may already hold the accelerator."""
+    import multiprocessing
+    from concurrent.futures import ProcessPoolExecutor
+
+    return ProcessPoolExecutor(
+        max_workers=workers, mp_context=multiprocessing.get_context("spawn"))
+
+
 def _run_shard(args: Tuple) -> Tuple:
     """Worker entry: run one lock-step pass over a shard of lanes."""
     lanes, groups, processors, collect_tasks = args
@@ -762,6 +775,14 @@ def _run_shard(args: Tuple) -> Tuple:
     return (res.num_requests, res.arrival, res.first_start, res.last_finish,
             res.done, res.group_tasks, res.busy, res.horizon, res.tasks,
             res.nr_max)
+
+
+_log = logging.getLogger(__name__)
+
+#: Batches that asked for the compiled core but ran on numpy, by
+#: ``batchsim_compiled.last_stats["reason"]`` — a run meant for the device
+#: reads this to prove it stayed there.
+compiled_fallbacks: Counter = Counter()
 
 
 def run_batch(
@@ -787,31 +808,33 @@ def run_batch(
     bit-exact parity tier) or ``"compiled"`` (the jitted
     ``jax.lax.while_loop`` core from :mod:`repro.core.batchsim_compiled`,
     documented float tolerance). The compiled backend runs in-process and
-    transparently falls back to numpy when a lane needs features it does
-    not support (``collect_tasks``) or its fixed queue capacity overflows.
+    falls back to numpy when a lane needs features it does not support
+    (``collect_tasks``) or exceeds its static bounds; each such bounds
+    fallback is counted in :data:`compiled_fallbacks` and logged.
     """
     if engine == "compiled" and not collect_tasks:
-        from .batchsim_compiled import run_batch_compiled
+        from . import batchsim_compiled
 
-        res = run_batch_compiled(lanes, groups, processors)
+        res = batchsim_compiled.run_batch_compiled(lanes, groups, processors)
         if res is not None:
             return res
-        # unsupported shape or capacity overflow: bit-exact numpy fallback
+        reason = batchsim_compiled.last_stats["reason"]
+        compiled_fallbacks[reason] += 1
+        _log.warning("compiled batch core fell back to numpy for %d lanes: "
+                     "%s", len(lanes), reason)
     elif engine not in ("numpy", "compiled"):
         raise ValueError(f"unknown batch engine {engine!r}")
     min_lanes = SHARD_MIN_LANES if shard_min_lanes is None else shard_min_lanes
     if workers <= 1 or len(lanes) < max(2 * workers, min_lanes):
         return BatchSimulator(lanes, groups, processors).run(
             collect_tasks=collect_tasks)
-    from concurrent.futures import ProcessPoolExecutor
-
     shards: List[Sequence[BatchLane]] = [
         lanes[i::workers] for i in range(workers)]
     shards = [s for s in shards if s]
     args = [(list(s), groups, processors, collect_tasks) for s in shards]
     own_pool = pool is None
     if own_pool:
-        pool = ProcessPoolExecutor(max_workers=len(shards))
+        pool = shard_pool(len(shards))
     try:
         parts = list(pool.map(_run_shard, args))
     finally:

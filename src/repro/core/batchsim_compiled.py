@@ -30,17 +30,24 @@ The compiled tier is **not** contractually bit-exact; it is exact on
   double ops for this graph); the tolerance is the contract, the zero is
   the measurement. The numpy tier remains the bit-exact parity oracle.
 
-Fallbacks (transparent, handled by :func:`repro.core.batchsim.run_batch`)
--------------------------------------------------------------------------
-* ``collect_tasks=True`` — task-trace collection is python-side by design;
-* ready-queue overflow — each ``(lane, pid, priority class)`` FIFO ring has
-  a fixed capacity (host-computed from the lane's task-count bound, capped
-  at :data:`QUEUE_CAP_MAX`); blowing it sets an in-carry overflow flag and
-  the batch re-runs on the numpy tier, whose queues grow without bound;
+Fallbacks (counted and logged by :func:`repro.core.batchsim.run_batch`)
+----------------------------------------------------------------------
+* ``collect_tasks=True`` — task-trace collection is python-side by design,
+  so ``run_batch`` sends such batches to numpy without asking this core;
+* ready-queue bound — each ``(lane, pid, priority class)`` FIFO ring has
+  a fixed capacity (host-computed from the lane's task-count bound); a
+  bound above :data:`QUEUE_CAP_MAX` runs the batch on the numpy tier,
+  whose queues grow without bound (reason ``"queue-bound"``);
+* ready-queue overflow — an in-carry flag set when a ring would wrap
+  (impossible below the bound; reason ``"overflow"``);
 * iteration-cap guard — a generous host-computed event bound; hitting it
   (impossible by construction, like the numpy z-table bound) falls back
-  rather than hanging inside XLA;
-* missing/failed jax import — the module degrades to "always fall back".
+  rather than hanging inside XLA (reason ``"itercap"``).
+
+Each of the last three returns ``None`` with the reason in ``last_stats``;
+``run_batch`` counts it in ``batchsim.compiled_fallbacks`` and logs a
+warning, so a run that was meant for the device never silently stays on
+the host. JAX itself is required: a failed import is an error.
 
 Ready queues: FIFO rings instead of scanned slots
 -------------------------------------------------
@@ -55,7 +62,7 @@ therefore reduces to "first non-empty FIFO in class order" — one dispatch-
 token FIFO (class 0) plus one FIFO per priority rank — giving O(1) pushes
 and pops with no key storage and no scans, at any capacity.
 
-``float64`` everywhere: calls run under ``jax.experimental.enable_x64`` so
+``float64`` everywhere: calls run under ``jax.enable_x64(True)`` so
 the repo's global default (float32, required by the kernel/model stacks)
 is untouched.
 
@@ -68,8 +75,10 @@ from __future__ import annotations
 
 import math
 import random
+from collections import Counter
+from dataclasses import dataclass
 from functools import partial
-from typing import Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -90,22 +99,6 @@ QUEUE_CAP_MAX = 4096
 
 _BIGSEQ = np.int64(1) << 62
 
-_jax = None
-_jax_failed = False
-
-
-def _get_jax() -> Optional[object]:
-    """Lazy jax import; remember a failure so we only try once."""
-    global _jax, _jax_failed
-    if _jax is None and not _jax_failed:
-        try:
-            import jax  # noqa: F401
-
-            _jax = jax
-        except Exception:  # pragma: no cover - depends on environment
-            _jax_failed = True
-    return _jax
-
 
 def _bucket(n: int, lo: int = 1) -> int:
     """Round ``n`` up to a power of two (≥ ``lo``) — shape bucketing keeps
@@ -114,8 +107,9 @@ def _bucket(n: int, lo: int = 1) -> int:
     return 1 << (v - 1).bit_length()
 
 
-def _advance_factory(jax: object) -> object:
+def _advance_factory() -> object:
     """Build the jitted lock-step advance once per process."""
+    import jax
     import jax.numpy as jnp
     from jax import lax
 
@@ -490,43 +484,59 @@ def _advance_factory(jax: object) -> object:
 
 
 #: Diagnostics of the most recent :func:`run_batch_compiled` call:
-#: ``{"iters", "itercap", "overflow", "fallback"}``. Tests and the
-#: simspeed benchmark read this to tell a compiled run from a fallback.
+#: ``{"iters", "itercap", "overflow", "fallback"}``, plus ``"reason"`` on a
+#: fallback. Tests and the simspeed benchmark read this to tell a compiled
+#: run from a fallback.
 last_stats: dict = {}
+
+#: Running totals over every device run in this process: ``calls``, the
+#: lock-step iterations they took (``iters``) and their budget
+#: (``itercap``) — the lock-step loop pays for its longest lane.
+totals: Counter = Counter()
 
 _advance_cache = None
 
 
-def _advance_fn() -> Optional[object]:
+def advance_fn() -> object:
+    """The jitted lock-step loop ``advance(flags, tab)``, built once per
+    process; ``flags`` is static, ``tab`` the arrays of :class:`LaneTables`."""
     global _advance_cache
     if _advance_cache is None:
-        jax = _get_jax()
-        if jax is None:
-            return None
-        _advance_cache = _advance_factory(jax)
+        _advance_cache = _advance_factory()
     return _advance_cache
 
 
-def run_batch_compiled(
+@dataclass
+class LaneTables:
+    """Host-side inputs of one compiled batch: ``advance(flags, tab)``'s
+    arguments plus what :func:`run_batch_compiled` needs to unpack its
+    outputs into a :class:`repro.core.batchsim.BatchResult`."""
+
+    flags: Tuple
+    tab: Dict[str, np.ndarray]
+    lanes: List
+    groups: List[List[int]]
+    pids: List[int]
+    nr: np.ndarray
+    horizon: np.ndarray
+    group_tasks: np.ndarray
+    nr_bucket: int
+    itercap: int
+
+
+def build_tables(
     lanes: Sequence,
     groups: Sequence[Sequence[int]],
     processors: Sequence[Processor],
-) -> Optional[object]:
-    """Run a batch through the compiled core; ``None`` requests fallback.
+) -> Optional[LaneTables]:
+    """Precompute a batch's lane tables on the host; ``None`` when the
+    lanes' ready-queue bound exceeds :data:`QUEUE_CAP_MAX`.
 
-    Inputs (arrival tables, noise multipliers, straggler multipliers) are
-    precomputed host-side with the scalar engines' exact expressions; the
-    jitted loop then advances the shared frontier to quiescence. Returns a
-    :class:`repro.core.batchsim.BatchResult` (``tasks=None``) or ``None``
-    when jax is unavailable, a queue overflowed :data:`QUEUE_CAP`, or the
-    iteration guard tripped — the caller reruns on the bit-exact numpy
-    tier in those cases.
+    Arrival tables, noise multipliers and straggler multipliers use the
+    scalar engines' exact expressions; every array is padded to its shape
+    bucket so GA generations of jittering width share one compiled loop.
     """
-    advance = _advance_fn()
-    if advance is None:
-        return None
-    from .batchsim import BatchResult, BatchSimulator
-    from .faults import FaultStream  # noqa: F401  (host-side parity ref)
+    from .batchsim import BatchSimulator
 
     sim = BatchSimulator(lanes, groups, processors)
     lanes = sim.lanes
@@ -644,9 +654,6 @@ def run_batch_compiled(
     qbound = int((nr * group_tasks.sum(axis=1)).max())
     CAP = _bucket(qbound + 4)
     if CAP > QUEUE_CAP_MAX:
-        last_stats.clear()
-        last_stats.update(fallback=True, overflow=False, iters=0,
-                          itercap=0, reason="queue-bound")
         return None
 
     # generous per-lane event bound: arrivals + completions (tasks +
@@ -724,20 +731,49 @@ def run_batch_compiled(
         "itercap": np.int64(itercap),
     }
     flags = (G, P, NP, CAP, any_noise, any_fault, any_strag, any_dispatch)
+    return LaneTables(
+        flags=flags, tab=tab, lanes=lanes, groups=groups, pids=pids, nr=nr,
+        horizon=horizon, group_tasks=group_tasks, nr_bucket=NRB,
+        itercap=itercap)
 
-    jax = _get_jax()
-    from jax.experimental import enable_x64
 
-    with enable_x64():
-        jtab = {k: jax.numpy.asarray(v) for k, v in tab.items()}
+def run_batch_compiled(
+    lanes: Sequence,
+    groups: Sequence[Sequence[int]],
+    processors: Sequence[Processor],
+) -> Optional[object]:
+    """Run a batch through the compiled core; ``None`` requests fallback.
+
+    Builds the lane tables on the host (:func:`build_tables`), then the
+    jitted loop advances the shared frontier to quiescence. Returns a
+    :class:`repro.core.batchsim.BatchResult` (``tasks=None``), or ``None``
+    with ``last_stats["reason"]`` set when the queue bound exceeds
+    :data:`QUEUE_CAP_MAX`, a ring overflowed, or the iteration guard
+    tripped — the caller reruns on the bit-exact numpy tier then.
+    """
+    import jax
+
+    from .batchsim import BatchResult
+
+    last_stats.clear()
+    t = build_tables(lanes, groups, processors)
+    if t is None:
+        last_stats.update(fallback=True, overflow=False, iters=0,
+                          itercap=0, reason="queue-bound")
+        return None
+    W = len(t.lanes)
+    with jax.enable_x64(True):
+        jtab = {k: jax.numpy.asarray(v) for k, v in t.tab.items()}
         (arrival, first_start, last_finish, done, busy, overflow,
-         iters) = advance(flags, jtab)
+         iters) = advance_fn()(t.flags, jtab)
         overflow = bool(overflow)
         iters = int(iters)
-        last_stats.clear()
-        last_stats.update(iters=iters, itercap=itercap, overflow=overflow,
-                          fallback=overflow or iters >= itercap)
-        if overflow or iters >= itercap:
+        fallback = overflow or iters >= t.itercap
+        last_stats.update(iters=iters, itercap=t.itercap, overflow=overflow,
+                          fallback=fallback)
+        totals.update(calls=1, iters=iters, itercap=t.itercap)
+        if fallback:
+            last_stats["reason"] = "overflow" if overflow else "itercap"
             return None
         arrival = np.asarray(arrival)[:W]
         first_start = np.asarray(first_start)[:W]
@@ -746,8 +782,8 @@ def run_batch_compiled(
         busy = np.asarray(busy)[:W]
 
     return BatchResult(
-        lanes=lanes, groups=groups, num_requests=nr, arrival=arrival,
+        lanes=t.lanes, groups=t.groups, num_requests=t.nr, arrival=arrival,
         first_start=first_start, last_finish=last_finish, done=done,
-        group_tasks=group_tasks, busy=busy, horizon=horizon,
-        pids=pids, nr_max=NRB, tasks=None,
+        group_tasks=t.group_tasks, busy=busy, horizon=t.horizon,
+        pids=t.pids, nr_max=t.nr_bucket, tasks=None,
     )

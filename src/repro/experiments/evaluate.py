@@ -82,9 +82,9 @@ class SweepConfig:
     use_batch: bool = False
     batch_workers: int = 1
     # Batched-engine selection: "numpy" (bit-exact lock-step) or "compiled"
-    # (jitted jax.lax.while_loop core; documented float tolerance, falls
-    # back to numpy transparently when jax is unavailable or the workload
-    # is unsupported). See repro.core.batchsim_compiled.
+    # (jitted jax.lax.while_loop core on the accelerator; documented float
+    # tolerance, falls back to numpy — counted and logged — when the
+    # workload exceeds its static bounds). See repro.core.batchsim_compiled.
     batch_engine: str = "numpy"
     # Device-in-the-loop conformance: after picking Puzzle's best schedule,
     # execute it on the virtual-clock PuzzleRuntime and diff the task trace

@@ -44,6 +44,7 @@ import time
 from concurrent.futures import FIRST_COMPLETED, ProcessPoolExecutor, wait
 from typing import Callable, Dict, Optional, Sequence
 
+from ..compile_cache import use_compile_cache
 from .aggregate import aggregate_results
 from .evaluate import (
     METHODS,
@@ -133,11 +134,18 @@ def run_sweep(
     ``workers <= 1`` evaluates inline (no process pool — handy under test
     and for debugging); otherwise scenarios fan out over a
     ``ProcessPoolExecutor(workers)`` whose initializer builds one shared
-    :class:`EvalContext` per worker. Returns the full results document
-    (``{"config", "scenarios", "aggregate"}``) with scenarios in index
-    order; per-scenario wall times are in seconds.
+    :class:`EvalContext` per worker. ``batch_engine="compiled"`` needs the
+    accelerator, so it requires ``workers <= 1`` (``ValueError``). Returns
+    the full results document (``{"config", "scenarios", "aggregate"}``)
+    with scenarios in index order; per-scenario wall times are in seconds.
     """
     config = config or SweepConfig()
+    if workers > 1 and config.batch_engine == "compiled":
+        # each pool worker would reach for the one accelerator this
+        # process may already hold
+        raise ValueError(
+            "batch_engine='compiled' runs on the accelerator, which one "
+            "process owns: use workers=1")
     log = log or (lambda msg: None)
     _check_run_dir(run_dir, config, force)
 
@@ -287,9 +295,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                     choices=["numpy", "compiled"],
                     help="batched-pass engine (with --use-batch): 'numpy' "
                          "is bit-exact; 'compiled' runs the jitted "
-                         "lock-step core (documented float tolerance, "
-                         "transparent numpy fallback; see "
-                         "BENCH_simspeed.json for the measured speedup)")
+                         "lock-step core on the accelerator (documented "
+                         "float tolerance, counted numpy fallback; needs "
+                         "--workers 1)")
     ap.add_argument("--prescreen", action="store_true",
                     help="route GA offspring through the static schedule "
                          "linter (repro.analysis) before simulation and "
@@ -348,6 +356,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         + ("" if args.arrival == "periodic" else f"_a{args.arrival}")
         + ("" if args.faults == "none" else f"_f{args.faults}"))
 
+    use_compile_cache()
     t0 = time.perf_counter()
     doc = run_sweep(specs, config, run_dir=run_dir, workers=args.workers,
                     force=args.force, log=lambda m: print(m, flush=True))

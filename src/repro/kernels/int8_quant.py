@@ -9,12 +9,10 @@ Oracle: ``repro.kernels.ref.quantize_ref``.
 """
 from __future__ import annotations
 
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from .compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _quant_kernel(x_ref, q_ref, scale_ref):
@@ -51,7 +49,7 @@ def quantize_int8(
             jax.ShapeDtypeStruct((nr * block_rows, c), jnp.int8),
             jax.ShapeDtypeStruct((nr * block_rows,), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)
         ),
         interpret=interpret,

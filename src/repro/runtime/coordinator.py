@@ -101,8 +101,18 @@ class Coordinator:
             self._deps.append(deps)
             self._succs.append(succs)
             self._owner.append(owner)
+        # real mode: where each subgraph argument comes from — None for the
+        # model input, else (producer subgraph, index in its outputs)
+        self._routes: List[List[List[Optional[Tuple[int, int]]]]] = []
         if not virtual:  # virtual mode replays costs; nothing to compile
-            for plist in placed:
+            for plist, owner in zip(placed, self._owner):
+                model = executables[plist[0].subgraph.graph.name]
+                bounds = [model.boundary(p.subgraph.layer_ids) for p in plist]
+                self._routes.append([
+                    [None if src < 0
+                     else (owner[src], bounds[owner[src]][1].index(src))
+                     for src, _ in ext]
+                    for ext, _ in bounds])
                 for p in plist:
                     w = workers[p.processor]
                     eng = w.engines[p.backend]
@@ -169,19 +179,18 @@ class Coordinator:
         p = self.placed[net][k]
         inputs = None
         if self._deps[net][k] and not self.virtual:
+            # a subgraph with no producer runs on its load-time example,
+            # which holds the model input
             inputs = []
-            for pk in self._deps[net][k]:
-                prod = self.placed[net][pk]
+            for route in self._routes[net][k]:
+                if route is None:
+                    model = self.executables[p.subgraph.graph.name]
+                    inputs.append((model.model_input(), "fp32"))
+                    continue
+                pk, ix = route
                 out = st.outputs[(net, pk)]
-                first = out[0] if isinstance(out, tuple) else out
-                inputs.append((first, prod.dtype))
-            # boundary inputs must match the subgraph arity; replicate the
-            # producer output for multi-input boundaries
-            model = self.executables[p.subgraph.graph.name]
-            _, example = model.build_subgraph_fn(p.subgraph.layer_ids, p.dtype)
-            while len(inputs) < len(example):
-                inputs.append(inputs[-1])
-            inputs = inputs[: len(example)]
+                inputs.append((out[ix] if isinstance(out, tuple) else out,
+                               self.placed[net][pk].dtype))
         now = self.clock.now()
         rec = TaskRecord(
             group=st.group, request=st.group_request, network=net, sg_index=k,

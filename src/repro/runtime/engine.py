@@ -54,6 +54,10 @@ class Engine:
     def _prepare(self, fn: Callable, example: Tuple) -> Callable:
         raise NotImplementedError
 
+    def arg_dtypes(self, key: str) -> Tuple:
+        """The argument dtypes ``key``'s handle was warmed with at load."""
+        return tuple(a.dtype for a in self._handles[key][1])
+
     def execute(self, key: str, inputs: Optional[Sequence] = None):
         fn, example = self._handles[key]
         args = inputs if inputs is not None else example
@@ -99,6 +103,9 @@ class EagerEngine(Engine):
     name = "nnapi"
 
     def _prepare(self, fn, example):
+        # run once at load, like the jitted engines: every op's executable
+        # is then cached before the first timed execute
+        jax.block_until_ready(fn(*example))
         return fn
 
 

@@ -351,9 +351,9 @@ class PuzzleRuntime:
         Aggregated over every engine execution this runtime performed (all
         workers, all requests) — the device-in-the-loop measurements that
         feed back into the :class:`~repro.core.profiler.ProfileDB`. Per key
-        the slowest sample is discarded when three or more exist (the first
-        execution can pay a JIT recompilation for the staged input
-        signature) and the lower median of the rest is taken — the paper's
+        the slowest sample is discarded when three or more exist (a first
+        execution can pay one-off device warm-up) and the lower median of
+        the rest is taken — the paper's
         brief on-target execution medians repeats the same way. Empty in
         virtual mode (nothing is actually executed).
 

@@ -23,6 +23,7 @@ import threading
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from .clock import SimCostSource, WallClock
@@ -44,8 +45,6 @@ class WorkerTask:
     priority: Tuple
     payload: Any = field(compare=False)
 
-
-_DTYPE_NP = {"fp32": np.float32, "fp16": np.float32, "int8": np.float32}
 
 #: Stop sentinel. Its priority ``(-2,)`` sorts below every real key — task
 #: keys are ``(0, prio, seq)`` and dispatch tokens ``(-1, 0, seq)`` — so a
@@ -256,23 +255,32 @@ class Worker:
             t0 = self.clock.now()
             inputs = payload.get("inputs")
             prepared: List = []
+            staged: List[np.ndarray] = []  # pooled host buffers in use
             err: Optional[Exception] = None
             try:
                 if inputs is not None:
-                    for tensor, src_dtype in inputs:
+                    # stage in the dtypes the handle was compiled for: any
+                    # other dtype would retrace and compile inside execute
+                    arg_dtypes = self.engines[payload["backend"]].arg_dtypes(
+                        payload["engine_key"])
+                    for (tensor, src_dtype), dt in zip(inputs, arg_dtypes):
                         # dtype boundary: (de)quantize = convert through a
                         # pooled staging buffer (the Worker dequant path)
-                        want = payload["dtype"]
-                        if src_dtype != want:
-                            arr = np.asarray(tensor, dtype=_DTYPE_NP[want])
-                            arr = self.pool.stage(arr)
-                            prepared.append(arr)
+                        if src_dtype != payload["dtype"]:
+                            arr = self.pool.stage(np.asarray(tensor, dtype=dt))
                         else:
-                            prepared.append(self.transport.transfer(tensor))
+                            arr = self.transport.transfer(tensor)
+                        if isinstance(arr, np.ndarray):
+                            # onto the device here, overlapping the previous
+                            # execute; a device array also matches the
+                            # load-time warm-up's call signature
+                            staged.append(arr)
+                            arr = jax.device_put(arr)
+                        prepared.append(arr)
             except Exception as e:  # fail the request, not the thread
                 err = self._wrap_error(payload, "input staging", e)
             quant_t = self.clock.now() - t0
-            self._exec_queue.put((payload, prepared, quant_t, err))
+            self._exec_queue.put((payload, prepared, staged, quant_t, err))
 
     # -- execution thread -----------------------------------------------------
     def _exec_loop(self) -> None:
@@ -280,7 +288,7 @@ class Worker:
             item = self._exec_queue.get()
             if item is None:
                 return
-            payload, prepared, quant_t, err = item
+            payload, prepared, staged, quant_t, err = item
             t0 = self.clock.now()
             payload["started"] = t0
             if self.on_start is not None:
@@ -299,9 +307,8 @@ class Worker:
             exec_t = self.clock.now() - t0
             # staged input buffers are consumed by the engine call — return
             # them to the pool (the Tensor Pool recycling path, §5.3)
-            for arr in prepared:
-                if isinstance(arr, np.ndarray):
-                    self.pool.release(arr)
+            for arr in staged:
+                self.pool.release(arr)
             self.busy_time += exec_t + quant_t
             self.tasks_done += 1
             payload["quant_s"] = quant_t

@@ -115,14 +115,19 @@ class ExecutableMobileModel:
         self.channels = channels
         self.spatial = spatial
         key = jax.random.PRNGKey(seed)
+        # He-normal scale: activations stay O(1) through the whole conv
+        # stack, so outputs compared against the reference are not ~0
+        scale = (2.0 / (9 * channels)) ** 0.5
         self._weights: Dict[int, np.ndarray] = {}
         for layer in self.graph.layers:
             key, sub = jax.random.split(key)
             if layer.op_type in ("conv", "dwconv"):
                 self._weights[layer.index] = np.asarray(
-                    jax.random.normal(sub, (3, 3, channels, channels)) * 0.05,
+                    jax.random.normal(sub, (3, 3, channels, channels)) * scale,
                     dtype=np.float32,
                 )
+        self._input = np.asarray(
+            jax.random.normal(key, self.input_shape()), dtype=np.float32)
         self._jnp = jnp
         self._jax = jax
 
@@ -152,16 +157,23 @@ class ExecutableMobileModel:
     def input_shape(self) -> Tuple[int, int, int, int]:
         return (1, self.spatial, self.spatial, self.channels)
 
-    def build_subgraph_fn(
-        self, layer_ids: Sequence[int], dtype: str = "fp32"
-    ) -> Tuple[Callable, Tuple]:
-        """(fn, example_args) computing this subgraph from boundary inputs."""
-        jnp = self._jnp
-        dt = self._np_dtype(dtype)
+    def model_input(self) -> np.ndarray:
+        """The network's input tensor (float32, seeded)."""
+        return self._input
+
+    def boundary(
+        self, layer_ids: Sequence[int]
+    ) -> Tuple[List[Tuple[int, int]], List[int]]:
+        """A subgraph's interface, in :meth:`build_subgraph_fn` order.
+
+        Returns its external inputs as ``(src_layer, dst_layer)`` pairs, one
+        per argument (``src_layer == -1`` is the model input), and the
+        layers whose outputs it returns: every layer with an edge leaving
+        the subgraph, and the network's sinks.
+        """
         ids = sorted(layer_ids)
         id_set = set(ids)
-        # boundary inputs: one per external dependency + model input for sources
-        ext_inputs: List[Tuple[int, int]] = []  # (src_layer, dst_layer)
+        ext_inputs: List[Tuple[int, int]] = []
         for lid in ids:
             preds = [e.src for e in self.graph.in_edges[lid]]
             if not preds:
@@ -169,6 +181,26 @@ class ExecutableMobileModel:
             for p in preds:
                 if p not in id_set:
                     ext_inputs.append((p, lid))
+        out_ids = [lid for lid in ids
+                   if not self.graph.out_edges[lid]
+                   or any(e.dst not in id_set
+                          for e in self.graph.out_edges[lid])]
+        return ext_inputs, out_ids
+
+    def build_subgraph_fn(
+        self, layer_ids: Sequence[int], dtype: str = "fp32"
+    ) -> Tuple[Callable, Tuple]:
+        """(fn, example_args) computing this subgraph from boundary inputs.
+
+        ``fn`` returns the outputs of :meth:`boundary`'s output layers (one
+        array, or a tuple of several). The example of a model-input
+        argument is :meth:`model_input`; the others are filled with 0.1.
+        """
+        jnp = self._jnp
+        dt = self._np_dtype(dtype)
+        ids = sorted(layer_ids)
+        id_set = set(ids)
+        ext_inputs, out_ids = self.boundary(ids)
 
         def fn(*args):
             env: Dict[int, object] = {}
@@ -181,16 +213,47 @@ class ExecutableMobileModel:
                 for p in preds:
                     ins.append(env[p] if p in id_set else ext[(p, lid)])
                 env[lid] = self._apply_layer(lid, ins, dt)
-            outs = [env[lid] for lid in ids
-                    if all(e.dst not in id_set for e in self.graph.out_edges[lid])
-                    or not self.graph.out_edges[lid]]
+            outs = [env[lid] for lid in out_ids]
             return outs[0] if len(outs) == 1 else tuple(outs)
 
         shape = self.input_shape()
         args = tuple(
-            jnp.zeros(shape, dtype=dt) + 0.1 for _ in ext_inputs
+            jnp.asarray(self._input, dtype=dt) if src < 0
+            else jnp.zeros(shape, dtype=dt) + 0.1
+            for src, _ in ext_inputs
         )
         return fn, args
+
+    def reference_forward(self) -> np.ndarray:
+        """The whole network in plain float32 numpy on :meth:`model_input`.
+
+        The reference for executed schedules: layer by layer in index
+        (topological) order on the host, sharing nothing with the JAX
+        subgraph functions but the weights. Returns the sink's output.
+        """
+        g = self.graph
+        vals: Dict[int, np.ndarray] = {}
+        for layer in g.layers:
+            preds = [e.src for e in g.in_edges[layer.index]]
+            if layer.op_type == "add_merge":
+                out = vals[preds[0]]
+                for p in preds[1:]:
+                    out = out + vals[p]
+            else:
+                x = vals[preds[0]] if preds else self._input
+                out = _conv3x3_same(x, self._weights[layer.index])
+            vals[layer.index] = np.maximum(out, 0.0)
+        (sink,) = [lid for lid in vals if not g.out_edges[lid]]
+        return vals[sink]
+
+
+def _conv3x3_same(x: np.ndarray, w: np.ndarray) -> np.ndarray:
+    """NHWC x HWIO 3x3 cross-correlation, stride 1, zero "SAME" padding."""
+    xp = np.pad(x, ((0, 0), (1, 1), (1, 1), (0, 0)))
+    # (N, H, W, C, 3, 3): window (i, j) at (h, w) reads x[h+i-1, w+j-1]
+    patches = np.lib.stride_tricks.sliding_window_view(xp, (3, 3),
+                                                       axis=(1, 2))
+    return np.tensordot(patches, w, axes=([4, 5, 3], [0, 1, 2]))
 
 
 def executable_zoo(

@@ -20,8 +20,6 @@ import random
 
 import pytest
 
-jax = pytest.importorskip("jax")
-
 from repro.core import (
     COMPILED_ABS_TOL,
     COMPILED_REL_TOL,
@@ -258,6 +256,26 @@ def test_run_batch_compiled_queue_bound_fallback():
     assert run_batch_compiled(big, groups, PROCS) is None
     assert bsc.last_stats["fallback"] is True
     assert bsc.last_stats["reason"] == "queue-bound"
+
+
+def test_run_batch_counts_and_logs_compiled_fallback(caplog):
+    """A queue-bound fallback taken by run_batch is never silent: it is
+    counted under its reason and logged, and the numpy tier answers."""
+    from repro.core import batchsim
+
+    rng = random.Random(34)
+    lanes, groups = _make_lanes(rng, 1, measured=False, arrivals_on=False,
+                                faults_on=False)
+    big = [BatchLane(spec=ln.spec, periods=ln.periods, num_requests=4000)
+           for ln in lanes]
+    before = dict(batchsim.compiled_fallbacks)
+    with caplog.at_level("WARNING", logger="repro.core.batchsim"):
+        res = run_batch(big, groups, PROCS, engine="compiled")
+    after = dict(batchsim.compiled_fallbacks)
+    assert after.pop("queue-bound") == before.pop("queue-bound", 0) + 1
+    assert after == before
+    assert "queue-bound" in caplog.text
+    assert len(res.makespans(0)) == 4000
 
 
 def test_run_batch_unknown_engine_rejected():

@@ -371,9 +371,6 @@ def test_compiled_tier_differential_spot_check():
     compiled tier's documented tolerance — observed diff is exactly 0.0.
     The exhaustive compiled suite (all golden traces, fallback contract)
     lives in tests/test_batchsim_compiled.py."""
-    import pytest
-
-    pytest.importorskip("jax")
     import repro.core.batchsim_compiled as bsc
     from repro.core import (
         COMPILED_ABS_TOL,

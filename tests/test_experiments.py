@@ -300,3 +300,53 @@ def test_sweep_rejects_config_mismatch(tmp_path):
     # --force wipes the stale per-scenario results and proceeds
     doc = run_sweep(specs, other, run_dir=str(tmp_path), workers=1, force=True)
     assert len(doc["scenarios"]) == 1
+
+
+def test_sweep_rejects_compiled_engine_with_workers(tmp_path, monkeypatch):
+    """Pool workers would each reach for the one accelerator: refused
+    before any pool starts or any file is written."""
+    import repro.experiments.sweep as sweep_mod
+
+    def no_pool(*args, **kwargs):
+        raise AssertionError("a process pool was started")
+
+    monkeypatch.setattr(sweep_mod, "ProcessPoolExecutor", no_pool)
+    specs = generate_scenario_specs(2, seed=1)
+    config = SweepConfig(use_batch=True, batch_engine="compiled")
+    run_dir = tmp_path / "run"
+    with pytest.raises(ValueError, match="workers=1"):
+        run_sweep(specs, config, run_dir=str(run_dir), workers=2)
+    assert not run_dir.exists()
+
+
+def test_compile_cache_placement(tmp_path):
+    """Importing repro sets no cache; the entry points' helper leaves a
+    set JAX_COMPILATION_CACHE_DIR to JAX and else uses <repo>/.jax_cache.
+    Checked in a child process, so no cache is set here."""
+    import os
+    import subprocess
+    import sys
+
+    from repro.compile_cache import REPO_CACHE_DIR
+
+    code = (
+        "import jax, repro, repro.core, repro.runtime, repro.experiments\n"
+        "print(jax.config.jax_compilation_cache_dir)\n"
+        "from repro.compile_cache import use_compile_cache\n"
+        "print(use_compile_cache())\n"
+        "print(jax.config.jax_compilation_cache_dir)\n"
+    )
+    src = str(REPO_CACHE_DIR.parent / "src")
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(PYTHONPATH=src, JAX_PLATFORMS="cpu")
+
+    def run(extra):
+        out = subprocess.run([sys.executable, "-c", code], env={**env, **extra},
+                             capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr
+        return out.stdout.split("\n")[:3]
+
+    assert run({}) == ["None", str(REPO_CACHE_DIR), str(REPO_CACHE_DIR)]
+    mine = str(tmp_path / "cache")
+    assert run({"JAX_COMPILATION_CACHE_DIR": mine}) == [mine, mine, mine]

@@ -6,6 +6,7 @@ real-execution tests (engine agreement, tensor pool, measured costs) keep
 exercising the threaded path but assert only on counts and values, never on
 timing.
 """
+import dataclasses
 import random
 import threading
 
@@ -402,3 +403,87 @@ def test_ablation_pool_reduces_mallocs(zoo):
                 rt.infer_sync([0, 1])
             counts[pool_on] = rt.stats()["pool"]["mallocs"]
     assert counts[True] <= counts[False]
+
+
+def _face_split(g):
+    """face_det in three subgraphs on three processors; both skip edges
+    (1->4, 6->9) and the chain cross subgraph boundaries."""
+    cut = {(2, 3), (1, 4), (7, 8), (6, 9)}
+    part = [1 if (e.src, e.dst) in cut else 0 for e in g.edges]
+    mapping = [0] * 3 + [1] * 5 + [2] * 4
+    return part, mapping
+
+
+def _sink_output(st, placed, model, net=0):
+    sink = model.graph.num_layers - 1
+    for k, p in enumerate(placed[net]):
+        if sink in p.subgraph.layer_ids:
+            out = st.outputs[(net, k)]
+            ix = model.boundary(p.subgraph.layer_ids)[1].index(sink)
+            return np.asarray(out[ix] if isinstance(out, tuple) else out,
+                              np.float32)
+    raise AssertionError("no subgraph holds the sink")
+
+
+def _rel_l2(out, ref):
+    return float(np.linalg.norm(out - ref) / np.linalg.norm(ref))
+
+
+@pytest.mark.parametrize("dtype,tol", [(0, 1e-5), (1, 5e-2)],
+                         ids=["fp32", "fp16"])
+def test_real_outputs_match_reference_forward(zoo, dtype, tol):
+    """A network split across processors computes what the whole network
+    computes: every subgraph argument is routed from the layer it reads."""
+    model = zoo["face_det"]
+    part, mapping = _face_split(model.graph)
+    sol = Solution(partition=[part], mapping=[mapping], priority=[0],
+                   dtype=[dtype], backend=[0])
+    with PuzzleRuntime([model.graph], sol, PROCS, zoo) as rt:
+        assert len(rt.placed[0]) == 3
+        st = rt.infer_sync([0])
+        out = _sink_output(st, rt.placed, model)
+    assert _rel_l2(out, model.reference_forward()) <= tol
+
+
+def test_cross_dtype_boundary_compiles_nothing_after_load(zoo):
+    """An fp32 producer feeding fp16 consumers: staged inputs take the
+    dtype the consumer's handle was warmed with, so serving adds no jit
+    cache entry after load."""
+    from repro.runtime.coordinator import Coordinator
+    from repro.runtime.engine import ENGINE_REGISTRY
+    from repro.runtime.worker import Worker
+
+    model = zoo["face_det"]
+    part, mapping = _face_split(model.graph)
+    sol = Solution(partition=[part], mapping=[mapping], priority=[0],
+                   dtype=[0], backend=[0])
+    placed = decode_solution(sol, [model.graph])
+    placed[0][1:] = [dataclasses.replace(p, dtype="fp16")
+                     for p in placed[0][1:]]
+    pool = TensorPool()
+    transport = SharedBufferTransport(pool)
+    coord = None
+    workers = {
+        p.pid: Worker(
+            p.pid, p.name, {n: make_engine(n) for n in ENGINE_REGISTRY},
+            pool, transport,
+            lambda *a: coord.on_task_done(*a),
+            on_start=lambda payload: coord.on_task_start(payload))
+        for p in PROCS
+    }
+    coord = Coordinator(placed, workers, zoo)
+    handles = [eng._handles[key][0] for w in workers.values()
+               for eng in w.engines.values() for key in eng._handles]
+    sizes = [h._cache_size() for h in handles]
+    for w in workers.values():
+        w.start()
+    try:
+        st = coord.submit([0])
+        st.future.result(timeout=60)
+    finally:
+        for w in workers.values():
+            w.stop()
+    assert [h._cache_size() for h in handles] == sizes
+    assert pool.stats.memcpy_calls == 2  # the two fp32->fp16 inputs
+    out = _sink_output(st, placed, model)
+    assert _rel_l2(out, model.reference_forward()) <= 5e-2
