@@ -1,0 +1,9 @@
+"""Share of the traced serving window in which no operation ran on the
+device."""
+
+
+def read(r):
+    trace = r.get("trace")
+    if r.get("kind") != "serve" or trace is None or trace.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - trace.busy_s / trace.window_s)
