@@ -22,14 +22,17 @@ if TYPE_CHECKING:  # typing-only: core must not import these at runtime
     from ..runtime.conformance import ConformanceReport
     from .batchsim import BatchResult
 
+from ..spans import span
 from .arrivals import ArrivalSpec
 from .baselines import best_mapping_solutions, npu_only_solution
 from .batchsim import BatchLane, batch_objectives, run_batch, shard_pool
+from .batchsim_compiled import totals as batch_totals
 from .chromosome import Solution, SolutionFactory, decode_solution
 from .comm import PiecewiseLinearCommModel
 from .fastsim import FastSimSpec, FastSimulator, SpecBuilder
 from .faults import FaultSpec
 from .ga import GAConfig, GAResult, GeneticScheduler
+from .ga import totals as ga_totals
 from .processors import Processor
 from .profiler import Profiler
 from .scenarios import Scenario, base_periods, best_model_times
@@ -341,29 +344,30 @@ class StaticAnalyzer:
         """
         alpha = alpha if alpha is not None else self.cfg.search_alpha
         num_requests = num_requests or self.cfg.fast_requests
-        keys = [
-            (self.solution_spec(s).signature(), alpha, num_requests, measured,
-             self._arrival_key, self._fault_key)
-            for s in solutions
-        ]
         lane_of_key: Dict[Tuple, int] = {}
         lanes: List[BatchLane] = []
-        for sol, key in zip(solutions, keys):
-            if key in self._objective_cache:
-                # count + refresh exactly like the scalar path's hit, so
-                # batch-mode hit rates are honest and the LRU eviction
-                # order stays identical to calling objectives() in a loop
-                self.objective_cache_hits += 1
-                self._objective_cache.move_to_end(key)
-                continue
-            if key in lane_of_key:
-                # in-generation duplicate: the scalar loop's second call
-                # would hit the cache, so report it as a hit here too
-                self.objective_cache_hits += 1
-                continue
-            self.objective_cache_misses += 1
-            lane_of_key[key] = len(lanes)
-            lanes.append(self._lane(sol, alpha, num_requests, measured))
+        with span("puzzle.batch.lanes", batch_totals):
+            keys = [
+                (self.solution_spec(s).signature(), alpha, num_requests,
+                 measured, self._arrival_key, self._fault_key)
+                for s in solutions
+            ]
+            for sol, key in zip(solutions, keys):
+                if key in self._objective_cache:
+                    # count + refresh exactly like the scalar path's hit, so
+                    # batch-mode hit rates are honest and the LRU eviction
+                    # order stays identical to calling objectives() in a loop
+                    self.objective_cache_hits += 1
+                    self._objective_cache.move_to_end(key)
+                    continue
+                if key in lane_of_key:
+                    # in-generation duplicate: the scalar loop's second call
+                    # would hit the cache, so report it as a hit here too
+                    self.objective_cache_hits += 1
+                    continue
+                self.objective_cache_misses += 1
+                lane_of_key[key] = len(lanes)
+                lanes.append(self._lane(sol, alpha, num_requests, measured))
         fresh: List[Tuple[float, ...]] = []
         if lanes:
             result = run_batch(
@@ -508,14 +512,15 @@ class StaticAnalyzer:
         lane_of_key: Dict[Tuple, int] = {}
         lanes: List[BatchLane] = []
         keys: List[Tuple] = []
-        for sol, alpha in requests:
-            key = (self.solution_spec(sol).signature(), alpha,
-                   self._arrival_key, self._fault_key)
-            keys.append(key)
-            if key not in lane_of_key:
-                lane_of_key[key] = len(lanes)
-                lanes.append(self._lane(sol, alpha, num_requests,
-                                        measured, seed=seed))
+        with span("puzzle.batch.lanes", batch_totals):
+            for sol, alpha in requests:
+                key = (self.solution_spec(sol).signature(), alpha,
+                       self._arrival_key, self._fault_key)
+                keys.append(key)
+                if key not in lane_of_key:
+                    lane_of_key[key] = len(lanes)
+                    lanes.append(self._lane(sol, alpha, num_requests,
+                                            measured, seed=seed))
         result = run_batch(
             lanes, self.scenario.groups, self.processors,
             workers=self.cfg.batch_workers, pool=self._pool(),
@@ -840,6 +845,14 @@ class StaticAnalyzer:
 
     # -- search ------------------------------------------------------------
     def run_ga(self, seeds: Sequence[Solution] = ()) -> GAResult:
+        """One search. Span ``puzzle.ga.run`` counts searches; its self time
+        is the seeding (the Best Mapping archive and its evaluations) and
+        the loop's bookkeeping outside the operators' and evaluations'
+        spans."""
+        with span("puzzle.ga.run", ga_totals):
+            return self._run_ga(seeds)
+
+    def _run_ga(self, seeds: Sequence[Solution]) -> GAResult:
         scheduler = GeneticScheduler(
             factory=self.factory,
             evaluate_fast=lambda s: self.objectives(s, num_requests=self.cfg.fast_requests),

@@ -82,6 +82,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..spans import span
 from .arrivals import arrival_horizon, draw_arrivals
 from .processors import Processor
 
@@ -277,6 +278,7 @@ def _advance_factory() -> object:
                               BIGSEQ)
             ci = jnp.argmin(smask, axis=1)
             act = tmin <= horizon
+            st["events"] = st["events"] + act.astype(jnp.int32)
             now = tmin
             t = now
 
@@ -474,11 +476,13 @@ def _advance_factory() -> object:
             "zpos": jnp.zeros((W,), i64),
             "fpos": jnp.zeros((W,), i64),
             "overflow": jnp.zeros((), bool),
+            "events": jnp.zeros((W,), jnp.int32),
             "it": jnp.zeros((), i64),
         }
         out = lax.while_loop(cond, body, st0)
         return (out["arrival"], out["first_start"], out["last_finish"],
-                out["done"], out["busy"], out["overflow"], out["it"])
+                out["done"], out["busy"], out["overflow"], out["events"],
+                out["it"])
 
     return advance
 
@@ -491,7 +495,14 @@ last_stats: dict = {}
 
 #: Running totals over every device run in this process: ``calls``, the
 #: lock-step iterations they took (``iters``) and their budget
-#: (``itercap``) — the lock-step loop pays for its longest lane.
+#: (``itercap``) — the lock-step loop pays for its longest lane. Lane
+#: occupancy: ``lane_events`` sums, over real lanes, the iterations in
+#: which the lane was active (one event each: an arrival, a deferred first
+#: arrival, a completion or dispatch token, or a delivery drain), and
+#: ``lane_slots`` is iterations × padded width, the lane-iterations the
+#: device computed. The spans ``puzzle.batch.tables`` (host lane tables,
+#: :func:`build_tables`) and ``puzzle.batch.lanes`` (lane assembly in the
+#: analyzer's batch entry points) add ``<span>.ns`` / ``<span>.n`` here.
 totals: Counter = Counter()
 
 _advance_cache = None
@@ -756,7 +767,8 @@ def run_batch_compiled(
     from .batchsim import BatchResult
 
     last_stats.clear()
-    t = build_tables(lanes, groups, processors)
+    with span("puzzle.batch.tables", totals):
+        t = build_tables(lanes, groups, processors)
     if t is None:
         last_stats.update(fallback=True, overflow=False, iters=0,
                           itercap=0, reason="queue-bound")
@@ -764,14 +776,16 @@ def run_batch_compiled(
     W = len(t.lanes)
     with jax.enable_x64(True):
         jtab = {k: jax.numpy.asarray(v) for k, v in t.tab.items()}
-        (arrival, first_start, last_finish, done, busy, overflow,
+        (arrival, first_start, last_finish, done, busy, overflow, events,
          iters) = advance_fn()(t.flags, jtab)
         overflow = bool(overflow)
         iters = int(iters)
         fallback = overflow or iters >= t.itercap
         last_stats.update(iters=iters, itercap=t.itercap, overflow=overflow,
                           fallback=fallback)
-        totals.update(calls=1, iters=iters, itercap=t.itercap)
+        totals.update(calls=1, iters=iters, itercap=t.itercap,
+                      lane_events=int(np.asarray(events)[:W].sum()),
+                      lane_slots=iters * len(t.tab["horizon"]))
         if fallback:
             last_stats["reason"] = "overflow" if overflow else "itercap"
             return None

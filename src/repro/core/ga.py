@@ -11,9 +11,11 @@ without average-score improvement.
 from __future__ import annotations
 
 import random
+from collections import Counter
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+from ..spans import span
 from .chromosome import Solution, SolutionFactory
 from .nsga import fast_non_dominated_sort, nsga3_select
 
@@ -25,6 +27,15 @@ BatchEvalFn = Callable[[Sequence[Solution], bool], List[Objective]]
 # chromosome (simulating it could never beat any feasible candidate),
 # or None when the analyzer cannot prove anything — the sound default.
 PrescreenFn = Callable[[Solution], Optional[Objective]]
+
+#: Span totals of the GA's host work over every search in this process
+#: (``<span>.ns`` self time, ``<span>.n`` count): ``puzzle.ga.mate``
+#: (shuffle and mating), ``puzzle.ga.local`` (local search),
+#: ``puzzle.ga.select`` (non-dominated sorts and NSGA-III selection),
+#: ``puzzle.ga.eval`` (every candidate evaluation the scheduler calls,
+#: which the operators' self time leaves out) and ``puzzle.ga.run`` (one
+#: per ``StaticAnalyzer.run_ga``).
+totals: Counter = Counter()
 
 
 @dataclass
@@ -146,7 +157,8 @@ class GeneticScheduler:
         obj = self._prescreen(sol)
         if obj is None:
             fn = self.evaluate_accurate if accurate else self.evaluate_fast
-            obj = fn(sol)
+            with span("puzzle.ga.eval", totals):
+                obj = fn(sol)
             self.evaluations += 1
         self._cache[key] = obj
         return obj
@@ -175,7 +187,8 @@ class GeneticScheduler:
                 else:
                     missing.append(s)
         if missing:
-            objs = self.evaluate_batch(missing, accurate)
+            with span("puzzle.ga.eval", totals):
+                objs = self.evaluate_batch(missing, accurate)
             for s, obj in zip(missing, objs):
                 self._cache[(s.key(), accurate)] = obj
                 self.evaluations += 1
@@ -271,31 +284,36 @@ class GeneticScheduler:
         gen = 0
         for gen in range(1, cfg.max_generations + 1):
             # All candidates are parents (paper: avoid premature convergence).
-            parents = pop[:]
-            self.rng.shuffle(parents)
-            offspring = self._mate(parents)
+            with span("puzzle.ga.mate", totals):
+                parents = pop[:]
+                self.rng.shuffle(parents)
+                offspring = self._mate(parents)
             # whole-generation fast evaluation (batched when configured),
             # then the probabilistic local search pass per child
             for child, obj in zip(offspring, self._eval_generation(offspring)):
                 child.fitness = obj
-            for k, child in enumerate(offspring):
-                if self.rng.random() < cfg.p_local:
-                    child = self._local_merge(child)
-                    child = self._local_reposition(child)
-                    offspring[k] = child
+            with span("puzzle.ga.local", totals):
+                for k, child in enumerate(offspring):
+                    if self.rng.random() < cfg.p_local:
+                        child = self._local_merge(child)
+                        child = self._local_reposition(child)
+                        offspring[k] = child
             # Accurate ("brief on-target") evaluation of the candidates that
             # could enter the Pareto set, before the population update.
-            combined = pop + offspring
-            fits = [list(s.fitness) for s in combined]
-            front0 = fast_non_dominated_sort(fits, vectorized=cfg.vectorized_nsga)[0]
+            with span("puzzle.ga.select", totals):
+                combined = pop + offspring
+                fits = [list(s.fitness) for s in combined]
+                front0 = fast_non_dominated_sort(
+                    fits, vectorized=cfg.vectorized_nsga)[0]
             front0_objs = self._eval_generation(
                 [combined[ix] for ix in front0], accurate=True)
-            for ix, obj in zip(front0, front0_objs):
-                combined[ix].fitness = obj
-            fits = [list(s.fitness) for s in combined]
-            keep = nsga3_select(fits, cfg.pop_size, rng=self.rng,
-                                vectorized=cfg.vectorized_nsga)
-            pop = [combined[i] for i in keep]
+            with span("puzzle.ga.select", totals):
+                for ix, obj in zip(front0, front0_objs):
+                    combined[ix].fitness = obj
+                fits = [list(s.fitness) for s in combined]
+                keep = nsga3_select(fits, cfg.pop_size, rng=self.rng,
+                                    vectorized=cfg.vectorized_nsga)
+                pop = [combined[i] for i in keep]
 
             if (
                 self.measure_device is not None
@@ -337,16 +355,18 @@ class GeneticScheduler:
             if stale >= cfg.patience and gen >= cfg.min_generations:
                 break
 
-        fits = [list(s.fitness) for s in pop]
-        pareto_ix = fast_non_dominated_sort(fits, vectorized=cfg.vectorized_nsga)[0]
-        # dedupe identical chromosomes
-        seen = set()
-        pareto: List[Solution] = []
-        for i in pareto_ix:
-            k = pop[i].key()
-            if k not in seen:
-                seen.add(k)
-                pareto.append(pop[i])
+        with span("puzzle.ga.select", totals):
+            fits = [list(s.fitness) for s in pop]
+            pareto_ix = fast_non_dominated_sort(
+                fits, vectorized=cfg.vectorized_nsga)[0]
+            # dedupe identical chromosomes
+            seen = set()
+            pareto: List[Solution] = []
+            for i in pareto_ix:
+                k = pop[i].key()
+                if k not in seen:
+                    seen.add(k)
+                    pareto.append(pop[i])
         return GAResult(
             pareto=pareto, history=history, generations=gen,
             evaluations=self.evaluations, oracle_drift=oracle_drift,

@@ -25,7 +25,9 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from ..core.chromosome import PlacedSubgraph
 from ..core.simulator import TaskRecord
+from ..spans import span
 from .clock import WallClock
+from .engine import totals
 from .worker import DISPATCH_TOKEN, Worker
 
 
@@ -176,6 +178,12 @@ class Coordinator:
 
     # -- internal -----------------------------------------------------------
     def _dispatch(self, st: RequestState, net: int, k: int) -> None:
+        with span("puzzle.serve.dispatch", totals):
+            self._route(st, net, k)
+
+    def _route(self, st: RequestState, net: int, k: int) -> None:
+        """Release subgraph ``k`` of network ``net``: gather its inputs,
+        record its ``TaskRecord`` and submit it to its worker."""
         p = self.placed[net][k]
         inputs = None
         if self._deps[net][k] and not self.virtual:

@@ -9,14 +9,33 @@ slowest, reproducing Table 2's ordering). New engines register via
 """
 from __future__ import annotations
 
+import functools
+import re
 import threading
 import time
-from collections import deque
+from collections import Counter, deque
 from typing import Any, Callable, Deque, Dict, Optional, Sequence, Tuple
 
 import jax
 
 from ..core.chromosome import PlacedSubgraph
+from ..spans import span
+
+#: Span totals of the serving path over every runtime in this process
+#: (``<span>.ns`` self time, ``<span>.n`` count): ``puzzle.serve.dispatch``
+#: (``Coordinator._dispatch``), ``puzzle.serve.stage`` (a worker's staging
+#: thread, per task) and ``puzzle.serve.execute`` (:meth:`Engine.execute`,
+#: up to ``block_until_ready``).
+totals: Counter = Counter()
+
+
+def program_name(placed: PlacedSubgraph) -> str:
+    """The served program's name: ``puzzle_<network>_<first>_<last>``, its
+    network and the first and last of its layers. XLA names the program
+    ``jit_`` and this, so a trace's time splits by subgraph."""
+    ids = placed.subgraph.layer_ids
+    net = re.sub(r"\W", "_", placed.subgraph.graph.name)
+    return f"puzzle_{net}_{min(ids)}_{max(ids)}"
 
 
 class Engine:
@@ -48,6 +67,7 @@ class Engine:
                 fn, example = model.build_subgraph_fn(
                     placed.subgraph.layer_ids, placed.dtype
                 )
+                fn.__name__ = program_name(placed)
                 self._handles[key] = (self._prepare(fn, example), example)
         return key
 
@@ -61,13 +81,15 @@ class Engine:
     def execute(self, key: str, inputs: Optional[Sequence] = None):
         fn, example = self._handles[key]
         args = inputs if inputs is not None else example
-        t0 = self._timer()
-        out = fn(*args)
-        jax.block_until_ready(out)
+        with span("puzzle.serve.execute", totals):
+            t0 = self._timer()
+            out = fn(*args)
+            jax.block_until_ready(out)
+            elapsed = self._timer() - t0
         samples = self.exec_times.get(key)
         if samples is None:
             samples = self.exec_times[key] = deque(maxlen=self.MAX_SAMPLES)
-        samples.append(self._timer() - t0)
+        samples.append(elapsed)
         return out
 
 
@@ -89,6 +111,7 @@ class FastMathJitEngine(Engine):
     name = "xnnpack"
 
     def _prepare(self, fn, example):
+        @functools.wraps(fn)
         def wrapped(*a):
             with jax.default_matmul_precision("bfloat16"):
                 return fn(*a)

@@ -34,6 +34,7 @@ from ..core.simulator import NoiseModel
 from .clock import SimCostSource, VirtualClock, WallClock
 from .coordinator import Coordinator, RequestState
 from .engine import ENGINE_REGISTRY, make_engine
+from .engine import totals as engine_totals
 from .recovery import RecoveryEvent, greedy_remap
 from .tensorpool import SharedBufferTransport, TensorPool
 from .worker import DISPATCH_TOKEN, Worker
@@ -382,6 +383,15 @@ class PuzzleRuntime:
         return out
 
     def stats(self) -> Dict[str, Any]:
+        """Operator counters: tensor pool, transport, per-worker busy time
+        and tasks, and ``spans``: each serving span's count and mean self
+        time in µs (``puzzle.serve.dispatch``, ``.stage``, ``.execute``),
+        totals over every runtime in this process."""
+        totals = dict(engine_totals)
+        spans = {key[:-2]: {"n": n, "mean_us": totals[key[:-2] + ".ns"]
+                            / n / 1e3}
+                 for key, n in sorted(totals.items())
+                 if key.endswith(".n") and n}
         return {
             "pool": self.pool.stats.__dict__,
             "transport": self.transport.stats.__dict__,
@@ -389,6 +399,7 @@ class PuzzleRuntime:
                 pid: {"busy_s": w.busy_time, "tasks": w.tasks_done}
                 for pid, w in self.workers.items()
             },
+            "spans": spans,
         }
 
     # -- lifecycle ----------------------------------------------------------

@@ -26,8 +26,9 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
+from ..spans import span
 from .clock import SimCostSource, WallClock
-from .engine import Engine
+from .engine import Engine, totals
 from .recovery import RecoveryPolicy
 from .tensorpool import SharedBufferTransport, TensorPool
 
@@ -251,36 +252,42 @@ class Worker:
             if task.payload is _STOP:
                 self._exec_queue.put(None)
                 return
-            payload = task.payload
-            t0 = self.clock.now()
-            inputs = payload.get("inputs")
-            prepared: List = []
-            staged: List[np.ndarray] = []  # pooled host buffers in use
-            err: Optional[Exception] = None
-            try:
-                if inputs is not None:
-                    # stage in the dtypes the handle was compiled for: any
-                    # other dtype would retrace and compile inside execute
-                    arg_dtypes = self.engines[payload["backend"]].arg_dtypes(
-                        payload["engine_key"])
-                    for (tensor, src_dtype), dt in zip(inputs, arg_dtypes):
-                        # dtype boundary: (de)quantize = convert through a
-                        # pooled staging buffer (the Worker dequant path)
-                        if src_dtype != payload["dtype"]:
-                            arr = self.pool.stage(np.asarray(tensor, dtype=dt))
-                        else:
-                            arr = self.transport.transfer(tensor)
-                        if isinstance(arr, np.ndarray):
-                            # onto the device here, overlapping the previous
-                            # execute; a device array also matches the
-                            # load-time warm-up's call signature
-                            staged.append(arr)
-                            arr = jax.device_put(arr)
-                        prepared.append(arr)
-            except Exception as e:  # fail the request, not the thread
-                err = self._wrap_error(payload, "input staging", e)
-            quant_t = self.clock.now() - t0
-            self._exec_queue.put((payload, prepared, staged, quant_t, err))
+            with span("puzzle.serve.stage", totals):
+                item = self._stage(task.payload)
+            self._exec_queue.put(item)
+
+    def _stage(self, payload: Any) -> Tuple:
+        """Convert and place one task's inputs: the execution thread's
+        queue item ``(payload, prepared, staged, quant_t, err)``."""
+        t0 = self.clock.now()
+        inputs = payload.get("inputs")
+        prepared: List = []
+        staged: List[np.ndarray] = []  # pooled host buffers in use
+        err: Optional[Exception] = None
+        try:
+            if inputs is not None:
+                # stage in the dtypes the handle was compiled for: any
+                # other dtype would retrace and compile inside execute
+                arg_dtypes = self.engines[payload["backend"]].arg_dtypes(
+                    payload["engine_key"])
+                for (tensor, src_dtype), dt in zip(inputs, arg_dtypes):
+                    # dtype boundary: (de)quantize = convert through a
+                    # pooled staging buffer (the Worker dequant path)
+                    if src_dtype != payload["dtype"]:
+                        arr = self.pool.stage(np.asarray(tensor, dtype=dt))
+                    else:
+                        arr = self.transport.transfer(tensor)
+                    if isinstance(arr, np.ndarray):
+                        # onto the device here, overlapping the previous
+                        # execute; a device array also matches the
+                        # load-time warm-up's call signature
+                        staged.append(arr)
+                        arr = jax.device_put(arr)
+                    prepared.append(arr)
+        except Exception as e:  # fail the request, not the thread
+            err = self._wrap_error(payload, "input staging", e)
+        quant_t = self.clock.now() - t0
+        return (payload, prepared, staged, quant_t, err)
 
     # -- execution thread -----------------------------------------------------
     def _exec_loop(self) -> None:
