@@ -66,18 +66,30 @@ and pops with no key storage and no scans, at any capacity.
 the repo's global default (float32, required by the kernel/model stacks)
 is untouched.
 
-A Pallas scatter kernel was considered and rejected for this CPU target:
-XLA already lowers the masked scatters to vectorized loops, and Pallas on
-CPU executes through the interpreter (the guide's TPU lowering does not
-apply), which benchmarks far slower than XLA's native lowering.
+One-hot reads and writes
+------------------------
+The loop's carry and tables are small per-lane arrays, so on TPU an
+iteration costs its number of kernels, not its arithmetic: every gather,
+scatter and dynamic-update-slice is a kernel of its own, and each 64-bit
+array is a pair of 32-bit ones. Compiled for v5e at GA width (80 lanes),
+indexed reads and column writes gave the body 201 fusions (69 of them
+gathers) and 24 dynamic-update-slices when clean, and 240-250 fusions and
+40 dynamic-update-slices with noise, dispatch load and faults. Every read
+and write over a small static axis is therefore a one-hot select or
+masked reduction (:func:`oh_get`), which fuses with its neighbours: 156,
+174 and 182 fusions, none of them a dynamic-update-slice. A true gather
+is kept only for axes longer than :data:`ONE_HOT_MAX` (the FIFO rings'
+read, the noise and straggler tables), and a scatter only for the rings'
+push. ``tests/test_chip_compile.py`` holds the body to this.
 """
 from __future__ import annotations
 
 import math
+import operator
 import random
 from collections import Counter
 from dataclasses import dataclass
-from functools import partial
+from functools import partial, reduce
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -98,7 +110,63 @@ COMPILED_ABS_TOL = 1e-12
 #: numpy tier (its queues grow without bound).
 QUEUE_CAP_MAX = 4096
 
+#: Largest per-lane axis that the lock-step loop reads as a one-hot masked
+#: reduction; a longer axis keeps a true gather. On TPU every gather is a
+#: kernel of its own (two for a 64-bit table, which the chip holds as a
+#: pair of 32-bit words): ~0.4 µs each at 16 lanes on one v5e, while a
+#: one-hot read fuses with its neighbours (~0.2 µs per reduction) but
+#: touches W × axis elements (× K for the delivery slots). The small
+#: static axes (workers, groups, priority classes, subgraphs, requests)
+#: lie below the limit, the FIFO rings (capacity ≥ 256 per worker and
+#: class) and the noise and straggler tables (one entry per draw) above.
+#: On one v5e a limit of 16, which reads the subgraph axis with gathers,
+#: cost 10-16% more per iteration, and one of 64, which reads the request
+#: axis with gathers, was within 2% of this one.
+ONE_HOT_MAX = 512
+
 _BIGSEQ = np.int64(1) << 62
+
+
+def oh_get(arr, *idx):
+    """``arr[lane, *idx]`` for every lane, as the lock-step loop reads it.
+
+    ``arr`` is ``(W, d1, ..., dk, *rest)`` and each of the ``k`` indices
+    is ``(W,)`` or ``(W, K)``; the result is ``idx.shape + rest``. The
+    indexed axes, ``d1 × ... × dk`` entries together, are read with a
+    one-hot mask up to :data:`ONE_HOT_MAX` entries and with a gather
+    above. Every index must be in range: a gather clamps one that is not,
+    a one-hot read returns zero. The read is exact and keeps
+    ``arr``'s dtype: booleans reduce with ``any``, integers with a ``sum``
+    in their own dtype (not widened under x64), floats with a ``sum`` of
+    the one selected term and ``+0.0`` for every other, which is exact
+    (``inf`` included) up to the sign of a zero.
+    """
+    import jax.numpy as jnp
+
+    W, k = arr.shape[0], len(idx)
+    dims, rest = arr.shape[1:1 + k], arr.shape[1 + k:]
+    idx = [jnp.asarray(i) for i in idx]
+    shape = jnp.broadcast_shapes(*(i.shape for i in idx))
+    lead = (1,) * (len(shape) - 1)
+    if math.prod(dims) > ONE_HOT_MAX:
+        return arr[(jnp.arange(W).reshape((W,) + lead), *idx)]
+    # one mask over the indexed axes, an outer product of one per axis:
+    # no reshape of the table, which on TPU would relayout it
+    masks = []
+    for a, (i, d) in enumerate(zip(idx, dims)):
+        pos = [1] * k
+        pos[a] = d
+        masks.append(jnp.broadcast_to(i, shape).reshape(shape + (1,) * k)
+                     == jnp.arange(d).reshape(pos))
+    hot = reduce(operator.and_, masks)
+    hot = hot.reshape(hot.shape + (1,) * len(rest))
+    a = arr.reshape((W,) + lead + dims + rest)
+    ax = tuple(range(len(shape), len(shape) + k))
+    if a.dtype == jnp.bool_:
+        return jnp.any(hot & a, axis=ax)
+    if jnp.issubdtype(a.dtype, jnp.floating):
+        return jnp.sum(jnp.where(hot, a, 0.0), axis=ax)
+    return jnp.sum(jnp.where(hot, a, 0), axis=ax, dtype=a.dtype)
 
 
 def _bucket(n: int, lo: int = 1) -> int:
@@ -128,17 +196,16 @@ def _advance_factory() -> object:
         dmax = tab["succ_pad"].shape[2]
         horizon = tab["horizon"]
         nr = tab["nr"]
-        proc_of = tab["proc_of"]
-        prio_of = tab["prio_of"]
-        exec_v = tab["exec_v"]
-        quant_v = tab["quant_v"]
-        comm_v = tab["comm_v"]
-        total_v = tab["total_v"]
+        # tables read at the same index, side by side: one read for each
+        # group, not one per table
+        place = jnp.stack([tab["proc_of"], tab["prio_of"]], -1)
+        cost = jnp.stack([tab["exec_v"], tab["total_v"], tab["quant_v"],
+                          tab["comm_v"]], -1)
+        succ = jnp.concatenate([tab["succ_cnt"][..., None],
+                                tab["succ_pad"]], -1)
+        roots = jnp.concatenate([tab["roots_n"][..., None], tab["roots"]],
+                                -1)
         dep_cnt = tab["dep_cnt"]
-        succ_pad = tab["succ_pad"]
-        succ_cnt = tab["succ_cnt"]
-        roots = tab["roots"]
-        roots_n = tab["roots_n"]
         overlap = tab["overlap"]
         dispatch_ov = tab["dispatch_ov"]
         dispatch_pid = tab["dispatch_pid"]
@@ -164,19 +231,23 @@ def _advance_factory() -> object:
         BIGSEQ = i64(_BIGSEQ)
         INF = jnp.float64(jnp.inf)
         M21 = i64((1 << 21) - 1)
+        HEAD = jnp.array([1, 0], i64)
+        TAIL = jnp.array([0, 1], i64)
 
-        # --- one-hot masked updates --------------------------------------
-        # XLA CPU's scatter lowering pays a per-updated-row cost (~0.1 µs)
-        # and this body issues hundreds of single-element updates per
-        # iteration — that row overhead, not arithmetic, dominated the
-        # first cut of this loop. Every update whose minor axis is small
-        # and static (frontier columns C, workers P, ring slots K,
-        # requests R) is therefore a fused elementwise select over a
-        # one-hot mask; only the FIFO rings (capacity axis) and the pend
-        # matrix keep true scatters.
+        # --- one-hot reads and writes -----------------------------------
+        # On TPU an iteration costs its number of kernels, and every
+        # gather, scatter and dynamic-update-slice is one of its own (two
+        # for a 64-bit array): see the module docstring. Every read and
+        # write over a small static axis (frontier columns C, workers P,
+        # ring slots K, groups G, requests R, subgraphs S, priority
+        # classes) is a one-hot select or masked reduction that fuses with
+        # its neighbours (:func:`oh_get`, ``oh_set``). Only axes longer
+        # than :data:`ONE_HOT_MAX` keep a gather (the FIFO rings' read, the
+        # noise and straggler tables); the rings' push keeps its scatter,
+        # as a one-hot write would rewrite every ring.
         def oh(m, col, width):
-            return m[:, None] & (col[:, None]
-                                 == jnp.arange(width)[None, :])
+            return m[:, None] & (jnp.asarray(col)[..., None]
+                                 == jnp.arange(width))
 
         def oh_set(arr, m, col, val):
             o = oh(m, col, arr.shape[1])
@@ -196,10 +267,8 @@ def _advance_factory() -> object:
             pack = ((pid + 1) << 42) | ((g + 1) << 21) | (rr + 1)
             st["del_pack"] = oh_set(st["del_pack"], m, pos, pack)
             we = m & (pos == 0)
-            st["times"] = st["times"].at[:, C - 1].set(
-                jnp.where(we, t, st["times"][:, C - 1]))
-            st["seqs"] = st["seqs"].at[:, C - 1].set(
-                jnp.where(we, st["seq"], st["seqs"][:, C - 1]))
+            st["times"] = oh_set(st["times"], we, C - 1, t)
+            st["seqs"] = oh_set(st["seqs"], we, C - 1, st["seq"])
             st["del_n"] = st["del_n"] + m
             st["seq"] = st["seq"] + m
             return st
@@ -208,18 +277,25 @@ def _advance_factory() -> object:
             """Append to the (pid, cls) FIFO ring; O(1), order = push order
             = release_seq order = the numpy tier's packed-key order."""
             pid_c = jnp.clip(pid, 0, P - 1)
-            pos = st["ftail"][WI, pid_c, cls]
-            head = st["fhead"][WI, pid_c, cls]
-            st["overflow"] = st["overflow"] | jnp.any(m & (pos - head >= CAP))
+            head, pos = oh_get(st["ring"], pid_c, cls).T
+            st["overflow"] = st["overflow"] | (m & (pos - head >= CAP))
             idx = pos & (CAP - 1)
             pid_s = jnp.where(m, pid, P)
             st["fifo"] = st["fifo"].at[WI, pid_s, cls, idx].set(
                 ((g + 1) << 21) | (rr + 1), mode="drop")
-            st["ftail"] = st["ftail"] + oh2(m, pid, cls, P, NP)
+            st["ring"] = (st["ring"]
+                          + oh2(m, pid, cls, P, NP)[..., None] * TAIL)
             return st
 
-        def release(st, m, g, rr, t):
-            """Reference ``release()``: dispatch token, then the task.
+        def placements(gs):
+            """(worker, priority) of each subgraph in ``gs`` (W, J): every
+            release of an event at once, ahead of the releases."""
+            pl = oh_get(place, jnp.clip(gs, 0, S - 1))
+            return pl[..., 0], pl[..., 1]
+
+        def release(st, m, g, rr, t, pid, prio):
+            """Reference ``release()``: dispatch token, then the task
+            (subgraph ``g`` on worker ``pid`` at priority ``prio``).
 
             Tokens carry no payload and only ever queue on the lane's
             single ``dispatch_pid``, so the token "FIFO" is a per-lane
@@ -228,17 +304,14 @@ def _advance_factory() -> object:
             if any_dispatch:
                 dm = m & dispatch_known
                 st["rel_seq"] = st["rel_seq"] + dm
-                d_idle = st["idle"][WI, dispatch_pid]
+                d_idle = oh_get(st["idle"], dispatch_pid)
                 st = append_deliver(st, dm & d_idle, dispatch_pid,
                                     neg1, neg1, t)
                 st["tok"] = st["tok"] + (dm & ~d_idle)
             st["rel_seq"] = st["rel_seq"] + m
-            g_c = jnp.clip(g, 0, S - 1)
-            pid = proc_of[WI, g_c]
-            is_idle = st["idle"][WI, pid]
+            is_idle = oh_get(st["idle"], pid)
             st = append_deliver(st, m & is_idle, pid, g, rr, t)
-            st = queue_push(st, m & ~is_idle, pid, prio_of[WI, g_c],
-                            g, rr)
+            st = queue_push(st, m & ~is_idle, pid, prio, g, rr)
             return st
 
         def pull_next(st, m, pid, t):
@@ -251,17 +324,16 @@ def _advance_factory() -> object:
                 st["tok"] = st["tok"] - tok_has
             else:
                 tok_has = jnp.zeros((W,), bool)
-            heads = st["fhead"][WI, pid_c]               # (W, NP)
-            tails = st["ftail"][WI, pid_c]
+            heads, tails = jnp.moveaxis(oh_get(st["ring"], pid_c), -1, 0)
             nonempty = heads < tails
             sel = jnp.argmax(nonempty, axis=1)           # first non-empty
             fifo_has = m & ~tok_has & jnp.any(nonempty, axis=1)
-            head_sel = jnp.take_along_axis(heads, sel[:, None], 1)[:, 0]
-            idx = head_sel & (CAP - 1)
-            v = st["fifo"][WI, pid_c, sel, idx]
+            idx = oh_get(heads, sel) & (CAP - 1)
+            v = oh_get(st["fifo"], pid_c, sel, idx)
             g = jnp.where(tok_has, -1, ((v >> 21) & M21) - 1)
             rr = jnp.where(tok_has, -1, (v & M21) - 1)
-            st["fhead"] = st["fhead"] + oh2(fifo_has, pid, sel, P, NP)
+            st["ring"] = (st["ring"]
+                          + oh2(fifo_has, pid, sel, P, NP)[..., None] * HEAD)
             has = tok_has | fifo_has
             st = append_deliver(st, has, pid, g, rr, t)
             st["idle"] = st["idle"] | oh(m & ~has, pid, P)
@@ -269,7 +341,7 @@ def _advance_factory() -> object:
 
         def cond(st):
             tmin = jnp.min(st["times"], axis=1)
-            return ((st["it"] < itercap) & ~st["overflow"]
+            return ((st["it"] < itercap) & ~jnp.any(st["overflow"])
                     & jnp.any(tmin <= horizon))
 
         def body(st):
@@ -285,24 +357,27 @@ def _advance_factory() -> object:
             # -- request arrivals -------------------------------------
             mA = act & (ci < G)
             gid = jnp.where(mA, ci, 0)
-            rid = st["src_rid"][WI, gid]
-            a0 = arrtab[WI, gid, 0]
+            rid = oh_get(st["src_rid"], gid)
+            arow = oh_get(arrtab, gid)                   # (W, NR)
+            a0 = arow[:, 0]
             defer = mA & (rid == 0) & (a0 > t)
             st["times"] = oh_set(st["times"], defer, gid, t + (a0 - t))
             st["seqs"] = oh_set(st["seqs"], defer, gid, st["seq"])
             st["seq"] = st["seq"] + defer
             arr_m = mA & ~defer
             rr = gid * NR + rid
-            st["arrival"] = jnp.where(oh(arr_m, rr, R), t[:, None],
-                                      st["arrival"])
-            st["pend"] = st["pend"].at[
-                WI, jnp.where(arr_m, rr, R)].set(dep_cnt, mode="drop")
+            o_a = oh(arr_m, rr, R)
+            st["arrival"] = jnp.where(o_a, t[:, None], st["arrival"])
+            st["pend"] = jnp.where(o_a[:, :, None], dep_cnt[:, None, :],
+                                   st["pend"])
+            rrow = oh_get(roots, gid)                    # (W, 1 + jmax)
+            pids, prios = placements(rrow[:, 1:])
             for j in range(jmax):
-                mj = arr_m & (j < roots_n[WI, gid])
-                st = release(st, mj, roots[WI, gid, j], rr, t)
+                st = release(st, arr_m & (j < rrow[:, 0]), rrow[:, 1 + j],
+                             rr, t, pids[:, j], prios[:, j])
             nrid = rid + 1
             has = arr_m & (nrid < nr)
-            arr_next = arrtab[WI, gid, jnp.minimum(nrid, NR - 1)]
+            arr_next = oh_get(arow, jnp.minimum(nrid, NR - 1))
             st["times"] = oh_set(
                 st["times"], arr_m, gid,
                 jnp.where(has, t + (arr_next - t), INF))
@@ -314,8 +389,8 @@ def _advance_factory() -> object:
             # -- worker completions -----------------------------------
             mC = act & (ci >= G) & (ci < G + P)
             pid = jnp.clip(ci - G, 0, P - 1)
-            g = st["end_g"][WI, pid]
-            rr = st["end_rr"][WI, pid]
+            g = oh_get(st["end_g"], pid)
+            rr = oh_get(st["end_rr"], pid)
             real = mC & (g >= 0)
             o_r = oh(real, rr, R)
             st["done"] = st["done"] + o_r
@@ -324,13 +399,26 @@ def _advance_factory() -> object:
                 st["last_finish"])
             g_c = jnp.clip(g, 0, S - 1)
             rr_c = jnp.clip(rr, 0, R - 1)
+            # the request's pending counts as one row, every successor's
+            # decrement at once: successor j reads its count less the
+            # decrements of successors 0..j that name the same subgraph,
+            # as the scalar loop's in-order decrements would leave it
+            prow = oh_get(st["pend"], rr_c)              # (W, S)
+            srow = oh_get(succ, g_c)                     # (W, 1 + dmax)
+            sjs = srow[:, 1:]                            # (W, dmax)
+            jj = jnp.arange(dmax)
+            mjs = real[:, None] & (jj < srow[:, :1])
+            same = ((sjs[:, :, None] == sjs[:, None, :]) & mjs[:, None, :]
+                    & (jj[:, None] >= jj[None, :]))
+            pjs = oh_get(prow, sjs) - jnp.sum(same, axis=2, dtype=prow.dtype)
+            dec = mjs[:, :, None] & (sjs[:, :, None] == jnp.arange(S))
+            prow = prow - jnp.sum(dec, axis=1, dtype=prow.dtype)
+            st["pend"] = jnp.where(o_r[:, :, None], prow[:, None, :],
+                                   st["pend"])
+            pids, prios = placements(sjs)
             for j in range(dmax):
-                mj = real & (j < succ_cnt[WI, g_c])
-                sj = succ_pad[WI, g_c, j]
-                pj = st["pend"][WI, rr_c, sj] - 1
-                st["pend"] = st["pend"].at[
-                    WI, jnp.where(mj, rr, R), sj].set(pj, mode="drop")
-                st = release(st, mj & (pj == 0), sj, rr, t)
+                st = release(st, mjs[:, j] & (pjs[:, j] == 0), sjs[:, j], rr,
+                             t, pids[:, j], prios[:, j])
             st["times"] = oh_set(st["times"], mC, G + pid, INF)
             st["seqs"] = oh_set(st["seqs"], mC, G + pid, BIGSEQ)
             st["end_g"] = oh_set(st["end_g"], mC, pid, i64(-2))
@@ -358,21 +446,20 @@ def _advance_factory() -> object:
             gj_c = jnp.clip(gj, 0, S - 1)
             disp = mk & (gj < 0)
             realm = mk & (gj >= 0)
-            WK = WI[:, None]
             tK = t[:, None]
             seq_at = st["seq"][:, None] + (jnp.cumsum(mk, axis=1) - mk)
             st["seq"] = st["seq"] + jnp.sum(mk, axis=1)
-            exec_t = exec_v[WK, gj_c]
-            total = total_v[WK, gj_c]
-            cm = jnp.where(overlap[:, None], 0.0, comm_v[WK, gj_c])
+            exec_t, total, quant, comm = jnp.moveaxis(
+                oh_get(cost, gj_c), -1, 0)
+            cm = jnp.where(overlap[:, None], 0.0, comm)
             if any_noise:
-                draw = realm & noisy[:, None] & sigma_pos[WK, pid_c]
+                draw = realm & noisy[:, None] & oh_get(sigma_pos, pid_c)
                 zat = st["zpos"][:, None] + (jnp.cumsum(draw, axis=1) - draw)
-                mult = emult[WK, jnp.minimum(zat, ZC - 1), pid_c]
+                mult = oh_get(emult, jnp.minimum(zat, ZC - 1), pid_c)
                 st["zpos"] = st["zpos"] + jnp.sum(draw, axis=1)
                 et = exec_t * mult
                 # same order as the scalar loop: exec + quant + (0|comm)
-                tt = et + quant_v[WK, gj_c] + cm
+                tt = et + quant + cm
                 exec_t = jnp.where(draw, et, exec_t)
                 total = jnp.where(draw, tt, total)
             if any_fault:
@@ -381,7 +468,7 @@ def _advance_factory() -> object:
                 if any_strag:
                     sd = fm & strag_on[:, None]
                     fat = st["fpos"][:, None] + (jnp.cumsum(sd, axis=1) - sd)
-                    sm = strag_tab[WK, jnp.minimum(fat, FC - 1)]
+                    sm = oh_get(strag_tab, jnp.minimum(fat, FC - 1))
                     st["fpos"] = st["fpos"] + jnp.sum(sd, axis=1)
                     ex_f = jnp.where(sd, ex_f * sm, ex_f)
                 for ti in range(T):
@@ -399,7 +486,7 @@ def _advance_factory() -> object:
                     stall = jnp.where(match, drop_t1[:, di, None] - tK,
                                       stall)
                     found = found | match
-                tt = ex_f + quant_v[WK, gj_c] + cm
+                tt = ex_f + quant + cm
                 tt = jnp.where(stall > 0.0, stall + tt, tt)
                 exec_t = jnp.where(fm, ex_f, exec_t)
                 total = jnp.where(fm, tt, total)
@@ -440,10 +527,8 @@ def _advance_factory() -> object:
                 jnp.sum(jnp.where(ohpr, rrj[:, :, None], i64(0)), axis=1),
                 st["end_rr"])
             st["del_n"] = jnp.where(mD, 0, st["del_n"])
-            st["times"] = st["times"].at[:, C - 1].set(
-                jnp.where(mD, INF, st["times"][:, C - 1]))
-            st["seqs"] = st["seqs"].at[:, C - 1].set(
-                jnp.where(mD, BIGSEQ, st["seqs"][:, C - 1]))
+            st["times"] = oh_set(st["times"], mD, C - 1, INF)
+            st["seqs"] = oh_set(st["seqs"], mD, C - 1, BIGSEQ)
 
             st["it"] = st["it"] + 1
             return st
@@ -468,20 +553,20 @@ def _advance_factory() -> object:
             "pend": jnp.zeros((W, R, S), jnp.int32),
             "busy": jnp.zeros((W, P)),
             "fifo": jnp.zeros((W, P, NP, CAP), i64),
-            "fhead": jnp.zeros((W, P, NP), i64),
-            "ftail": jnp.zeros((W, P, NP), i64),
+            "ring": jnp.zeros((W, P, NP, 2), i64),     # (head, tail)
             "tok": jnp.zeros((W,), i64),
             "del_pack": jnp.zeros((W, K), i64),
             "del_n": jnp.zeros((W,), i64),
             "zpos": jnp.zeros((W,), i64),
             "fpos": jnp.zeros((W,), i64),
-            "overflow": jnp.zeros((), bool),
+            "overflow": jnp.zeros((W,), bool),
             "events": jnp.zeros((W,), jnp.int32),
             "it": jnp.zeros((), i64),
         }
         out = lax.while_loop(cond, body, st0)
         return (out["arrival"], out["first_start"], out["last_finish"],
-                out["done"], out["busy"], out["overflow"], out["events"],
+                out["done"], out["busy"], jnp.any(out["overflow"]),
+                out["events"],
                 out["it"])
 
     return advance

@@ -302,3 +302,90 @@ def test_objectives_batch_compiled_engine_close_to_scalar():
         assert len(b) == len(s)
         for x, y in zip(b, s):
             assert _close(x, y)
+
+
+# -- the loop's read helper --------------------------------------------------
+
+
+def _table(rng, dtype, shape):
+    import numpy as np
+
+    if dtype == "bool":
+        return rng.random(shape) < 0.5
+    if dtype == "float64":
+        a = rng.normal(size=shape) * 1e3
+        special = rng.integers(0, 4, size=shape)
+        a = np.where(special == 0, np.inf, a)
+        a = np.where(special == 1, -0.0, a)
+        return a
+    info = np.iinfo(dtype)
+    return rng.integers(info.min, info.max, size=shape, dtype=dtype)
+
+
+@pytest.mark.parametrize("path", ["one_hot", "gather"])
+@pytest.mark.parametrize("form", ["lane", "lane_pair", "slots", "row"])
+@pytest.mark.parametrize("dtype", ["bool", "int32", "int64", "float64"])
+def test_oh_get_matches_indexing(dtype, form, path):
+    """``oh_get`` equals ``x[WI, i]`` / ``x[WI, i, j]`` / ``x[WK, i]``, in
+    the table's own dtype under x64, whether the axis is read with a
+    one-hot mask or with a gather (axis above ``ONE_HOT_MAX``); float
+    tables hold ``inf`` and ``-0.0``."""
+    import jax
+    import numpy as np
+
+    rng = np.random.default_rng(sum(map(ord, dtype + form + path)))
+    W, K = 5, 3
+    big = path == "gather"
+    d = bsc.ONE_HOT_MAX + 7 if big else 6
+    if form == "lane_pair":
+        dims = (3, -(-d // 3)) if big else (2, 3)
+        assert (np.prod(dims) > bsc.ONE_HOT_MAX) == big
+        shape = (W,) + dims
+    elif form == "row":
+        shape = (W, d, 4)
+    else:
+        shape = (W, d)
+    x = _table(rng, dtype, shape)
+    WI = np.arange(W)
+    if form == "lane_pair":
+        i = rng.integers(0, dims[0], size=W)
+        j = rng.integers(0, dims[1], size=W)
+        idx, want = (i, j), x[WI, i, j]
+    elif form == "slots":
+        i = rng.integers(0, d, size=(W, K))
+        idx, want = (i,), x[WI[:, None], i]
+    else:
+        i = rng.integers(0, d, size=W)
+        idx, want = (i,), x[WI, i]
+    with jax.enable_x64(True):
+        got = jax.jit(bsc.oh_get)(jax.numpy.asarray(x),
+                                   *map(jax.numpy.asarray, idx))
+        assert got.dtype == x.dtype
+        got = np.asarray(got)
+    assert got.shape == want.shape
+    # value for value (a zero's sign aside: -0.0 == 0.0)
+    assert np.array_equal(got, want)
+
+
+@pytest.mark.parametrize("limit", [0, 1 << 30], ids=["all_gathers",
+                                                      "all_one_hot"])
+def test_read_paths_give_the_same_loop(limit, monkeypatch):
+    """The loop's result does not depend on which axes are read with a
+    gather and which with a one-hot mask: with every read a gather, and
+    with every read one-hot, it equals the default's, value for value."""
+    import jax
+    import numpy as np
+
+    rng = random.Random(4100)
+    lanes, groups = _make_lanes(rng, 4, measured=True, arrivals_on=True,
+                                faults_on=True)
+    tables = bsc.build_tables(lanes, groups, PROCS)
+    with jax.enable_x64(True):
+        jtab = {k: jax.numpy.asarray(v) for k, v in tables.tab.items()}
+        want = [np.asarray(a) for a in bsc.advance_fn()(tables.flags, jtab)]
+        monkeypatch.setattr(bsc, "ONE_HOT_MAX", limit)
+        got = [np.asarray(a)
+               for a in bsc._advance_factory()(tables.flags, jtab)]
+    assert int(want[-1]) > 0
+    for a, b in zip(got, want):
+        assert np.array_equal(a, b)

@@ -8,6 +8,7 @@ pytest-xdist worker collects the same tests and only the worker given
 this file loads the TPU library.
 """
 import os
+import re
 
 import jax
 import numpy as np
@@ -17,6 +18,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import (
     AnalyzerConfig,
     BatchLane,
+    FaultSpec,
     NoiseModel,
     StaticAnalyzer,
     build_scenario,
@@ -64,14 +66,53 @@ def generation():
     return analyzer, sols
 
 
-@pytest.mark.parametrize("measured", [False, True],
-                         ids=["clean", "noisy_dispatch"])
-def test_advance_compiles_for_v5e(one_chip, generation, measured):
-    """The lock-step loop at GA width, for the GA's fast (clean) and
-    accurate (noise + dispatch load) evaluations."""
-    analyzer, sols = generation
+#: Ceiling on the lock-step loop body's fusions at GA width, each a
+#: kernel launched once per iteration on the chip. Indexed reads and
+#: writes gave 201, 240 and 250 (and 24-40 dynamic-update-slices);
+#: one-hot reads and writes give 156, 174 and 182, and the ceilings
+#: leave ~10% of room above those.
+FUSIONS = {"clean": 170, "noisy_dispatch": 190, "faulted": 200}
+
+
+def _body_ops(text):
+    """The while body's top-level operations of an optimized HLO module:
+    ``(op, result shape, ops inside a fusion)`` per instruction."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if m and not line.startswith(" "):
+            cur = comps.setdefault(m.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None and " = " in line:
+            cur.append(line.strip())
+
+    def parse(inst):
+        rhs = inst.split(" = ", 1)[1]
+        m = re.search(r" ([a-z][a-z0-9\-]*)\(", rhs)
+        return (m.group(1), rhs[:m.start()]) if m else ("", rhs)
+
+    bodies = [re.search(r"body=%?([\w.\-]+)", i).group(1)
+              for c in comps.values() for i in c if " while(" in i]
+    assert len(bodies) == 1, bodies
+    out = []
+    for inst in comps[bodies[0]]:
+        op, shape = parse(inst)
+        inner = []
+        if op == "fusion":
+            called = re.search(r"calls=%?([\w.\-]+)", inst).group(1)
+            inner = [parse(i) for i in comps[called]]
+        out.append((op, shape, inner))
+    return out
+
+
+def _lanes(analyzer, sols, kind):
     cfg = analyzer.cfg
-    lanes = [
+    measured = kind != "clean"
+    faults = FaultSpec(dropouts=((2, 0.02, 0.05),),
+                       throttles=((0, 0.01, 0.03, 2.0),),
+                       straggler_prob=0.1, straggler_shape=1.5)
+    return [
         BatchLane(
             spec=analyzer.solution_spec(s),
             periods=list(analyzer.base_periods),
@@ -81,14 +122,30 @@ def test_advance_compiles_for_v5e(one_chip, generation, measured):
             if measured else None,
             dispatch_overhead=cfg.dispatch_overhead if measured else 0.0,
             dispatch_pid=cfg.dispatch_pid,
+            faults=faults if kind == "faulted" else None,
         )
         for i, s in enumerate(sols)
     ]
-    tables = build_tables(lanes, analyzer.scenario.groups,
-                          analyzer.processors)
+
+
+@pytest.mark.parametrize("kind", ["clean", "noisy_dispatch", "faulted"])
+def test_advance_compiles_for_v5e(one_chip, generation, kind):
+    """The lock-step loop at GA width, for the GA's fast (clean) and
+    accurate (noise + dispatch load) evaluations and under faults
+    (stragglers, a throttle and a dropout). Its body reads and writes
+    its small per-lane axes with one-hot masks: no dynamic-update-slice,
+    no scatter but the FIFO rings' push, no gather but the large-axis
+    reads (the rings, and the noise and straggler tables), and fewer
+    fusions than the ceiling."""
+    analyzer, sols = generation
+    tables = build_tables(_lanes(analyzer, sols, kind),
+                          analyzer.scenario.groups, analyzer.processors)
     assert tables is not None
-    any_noise, any_dispatch = tables.flags[4], tables.flags[7]
+    _, P, NP, CAP, any_noise, any_fault, any_strag, any_dispatch = (
+        tables.flags)
+    measured = kind != "clean"
     assert (any_noise, any_dispatch) == (measured, measured)
+    assert (any_fault, any_strag) == (kind == "faulted",) * 2
     with jax.enable_x64(True):
         args = {
             k: jax.ShapeDtypeStruct(np.shape(v), np.asarray(v).dtype,
@@ -97,6 +154,21 @@ def test_advance_compiles_for_v5e(one_chip, generation, measured):
         }
         compiled = advance_fn().lower(tables.flags, args).compile()
     assert compiled.memory_analysis() is not None
+
+    body = _body_ops(compiled.as_text())
+    every = [(op, shape) for op, shape, inner in body
+             for op, shape in [(op, shape)] + inner]
+    assert not [s for op, s in every if op == "dynamic-update-slice"]
+    ring = f"[{LANES},{P},{NP},{CAP}]"
+    scatters = [s for op, s in every if op == "scatter"]
+    assert scatters and all(ring in s for s in scatters), scatters
+    # a 64-bit table is read as two 32-bit halves: up to two gathers per
+    # kept read (the rings' head, and the noise and straggler tables)
+    gathers = [op for op, _, inner in body
+               if op == "gather" or any(o == "gather" for o, _ in inner)]
+    assert len(gathers) <= 2 * (1 + any_noise + any_strag), gathers
+    fusions = sum(op == "fusion" for op, _, _ in body)
+    assert fusions <= FUSIONS[kind], fusions
 
 
 @pytest.fixture(scope="module")
