@@ -297,3 +297,10 @@ class Control(Driver):
                        for m in (False, True)}
         return (list(evaluations[True]), evaluations,
                 [low.alpha_star(s) for s in r["front"]])
+
+
+def toy(cell: harness.Cell) -> None:
+    """Shrink a resolved cell in place to the size the CPU tests run: a GA
+    of 6 over 2 generations, and a window of one round."""
+    cell.traffic["ga"] = {"pop_size": 6, "generations": 2}
+    cell.seconds = 0.0

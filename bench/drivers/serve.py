@@ -1,13 +1,15 @@
 """Serving driver: an open-loop periodic load on ``PuzzleRuntime``.
 
-Set-up builds the configuration's networks (``ExecutableMobileModel``,
-weights from the configuration's ``model_seed``, each network's input from
+Everything specific to the served networks comes from the configuration's
+network family (``harness.family_module``; the functions are listed in
+``bench/families/convnet.py``). Set-up builds the family's executables
+(weights fixed by the configuration, each network's input from
 ``--seed``), picks the served schedule with a GA of fixed size and seed on
-the paper's profile tables, and loads it into ``PuzzleRuntime``, which
-compiles and warms every placed subgraph. A few requests per group are
-then served one at a time, and an open-loop warm-up window at the cell's
-rate runs before the measured one: the first window after loading pays
-one-off costs.
+the paper's profile tables over the family's graphs, and loads it into
+``PuzzleRuntime``, which compiles and warms every placed subgraph. A few
+requests per group are then served one at a time, and an open-loop warm-up
+window at the cell's rate runs before the measured one: the first window
+after loading pays one-off costs.
 
 The window is an open loop: group ``g`` falls due every
 ``alpha * base_period[g]`` seconds, with ``alpha = alpha_knee / load`` (the
@@ -17,10 +19,11 @@ latency runs from its due time to the finish of its last subgraph; a
 request that fails or is not done ``drain_s`` after the window counts as
 failed, with infinite latency.
 
-The sink outputs of a seeded sample of requests (the first, the last and a
-few drawn from ``--seed``, per group) are kept; every other request's
+The outputs of every sink of each network (``graph.sinks()``: each layer
+with no out-edge) are kept for a seeded sample of requests (the first, the
+last and a few drawn from ``--seed``, per group); every other request's
 outputs are dropped when it completes. After the window each kept output
-is compared with the float32 reference of ``convnet.py``.
+is compared with the family's float32 reference.
 """
 from __future__ import annotations
 
@@ -34,7 +37,6 @@ from dataclasses import dataclass, field
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
 import harness
-import convnet
 
 
 @dataclass
@@ -45,7 +47,7 @@ class Request:
     submitted: float
     state: Any
     keep: bool
-    sinks: Dict[str, Any] = field(default_factory=dict)
+    sinks: Dict[str, List[Any]] = field(default_factory=dict)
     error: Optional[BaseException] = None
 
 
@@ -54,6 +56,7 @@ class Driver:
         self.cell = cell
         self.config = cell.config
         self.traffic = cell.traffic
+        self.family = harness.family_module(cell.config)
         self.requests: List[Request] = []
         self.window: Tuple[float, float] = (0.0, 0.0)
         self.trace_window: Optional[Tuple[float, float]] = None
@@ -64,19 +67,7 @@ class Driver:
 
     # -- set-up ----------------------------------------------------------------
     def build_zoo(self) -> Dict[str, Any]:
-        from repro.zoo import ExecutableMobileModel
-
-        zoo = {}
-        for name, shape in self.config["networks"].items():
-            s, c = shape["spatial"], shape["channels"]
-            model = ExecutableMobileModel(name, channels=c, spatial=s,
-                                          seed=self.config["model_seed"])
-            # the weights are compiled into the served programs as
-            # constants, so they stay fixed; the input varies with --seed
-            model._input = convnet.make_input(
-                s, c, harness.stable_seed(self.cell.seed, name))
-            zoo[name] = model
-        return zoo
+        return self.family.executables(self.config, self.cell.seed)
 
     def plan(self):
         """The served schedule: the GA's best on the profile tables."""
@@ -87,7 +78,7 @@ class Driver:
         ctx = EvalContext()
         scenario = build_scenario(
             self.config["name"], [list(g) for g in self.config["groups"]],
-            ctx.graphs)
+            self.family.graphs(self.config))
         p = self.config["plan"]
         analyzer = StaticAnalyzer(
             scenario, ctx.processors, ctx.profiler, ctx.comm_model,
@@ -114,21 +105,23 @@ class Driver:
                                 self.zoo, RuntimeConfig())
         self.alpha = self.config["alpha_knee"] / self.traffic["load"]
         self.set_alpha(self.alpha)
-        # where each network's sink output comes out: (subgraph, index)
-        self.sink_of: Dict[int, Tuple[int, Optional[int]]] = {}
+        # where each of a network's sinks comes out, in graph.sinks()
+        # order: (subgraph, index in its outputs, None if it has one)
+        self.sink_of: Dict[int, List[Tuple[int, Optional[int]]]] = {}
         self.work: Dict[Tuple[int, int], Tuple[float, float]] = {}
         for net, pl in enumerate(self.rt.placed):
             name = self.graphs[net].name
             shape = self.config["networks"][name]
-            sink = self.graphs[net].num_layers - 1
+            where = {}
             for k, p in enumerate(pl):
                 ids = p.subgraph.layer_ids
                 outs = self.zoo[name].boundary(ids)[1]
-                if sink in ids:
-                    self.sink_of[net] = (k, outs.index(sink)
-                                         if len(outs) > 1 else None)
-                self.work[(net, k)] = convnet.subgraph_work(
-                    name, ids, shape["spatial"], shape["channels"], p.dtype)
+                for lid in outs:
+                    where[lid] = (k, outs.index(lid) if len(outs) > 1
+                                  else None)
+                self.work[(net, k)] = self.family.work(name, ids, shape,
+                                                       p.dtype)
+            self.sink_of[net] = [where[s] for s in self.graphs[net].sinks()]
         for g, nets in enumerate(self.groups):
             for _ in range(self.traffic["warmup_requests"]):
                 self.rt.infer(nets, group=g).future.result(timeout=120)
@@ -154,12 +147,13 @@ class Driver:
             out.append(picks)
         return out
 
-    def _sinks(self, st) -> Dict[str, Any]:
+    def _sinks(self, st) -> Dict[str, List[Any]]:
         out = {}
         for net in st.networks:
-            k, ix = self.sink_of[net]
-            o = st.outputs[(net, k)]
-            out[self.graphs[net].name] = o if ix is None else o[ix]
+            out[self.graphs[net].name] = [
+                st.outputs[(net, k)] if ix is None
+                else st.outputs[(net, k)][ix]
+                for k, ix in self.sink_of[net]]
         return out
 
     def _on_done(self, req: Request, fut) -> None:
@@ -260,8 +254,8 @@ class Driver:
 
         for req in self.requests:
             if req.sinks:
-                req.sinks = {k: np.asarray(v, np.float32)
-                             for k, v in req.sinks.items()}
+                req.sinks = {k: [np.asarray(v, np.float32) for v in vs]
+                             for k, vs in req.sinks.items()}
                 self._kept.append(req)
         self.records = list(self.rt.coordinator.trace)
         self.rt.close()
@@ -269,22 +263,15 @@ class Driver:
         gc.collect()
 
     # -- correctness ---------------------------------------------------------
-    def reference(self, mode: str = "f32") -> Dict[str, Any]:
-        out = {}
-        for name, shape in self.config["networks"].items():
-            s, c = shape["spatial"], shape["channels"]
-            w = convnet.make_weights(name, s, c, self.config["model_seed"])
-            x = convnet.make_input(
-                s, c, harness.stable_seed(self.cell.seed, name))
-            out[name] = convnet.reference_forward(name, w, x, mode=mode)
-        return out
+    def reference(self, mode: str = "f32") -> Dict[str, List[Any]]:
+        return self.family.reference(self.config, self.cell.seed, mode)
 
-    def rel_l2(self, refs: Dict[str, Any]) -> Dict[str, float]:
+    def rel_l2(self, refs: Dict[str, List[Any]]) -> Dict[str, float]:
         """Worst rel-L2 of each network's kept outputs against ``refs``."""
         worst: Dict[str, float] = {}
         for req in self._kept:
-            for name, out in req.sinks.items():
-                err = convnet.rel_l2(out, refs[name])
+            for name, outs in req.sinks.items():
+                err = self.family.worst_rel_l2(name, outs, refs[name])
                 worst[name] = max(worst.get(name, 0.0), err)
         return worst
 
@@ -323,9 +310,8 @@ class Driver:
         done = [r for r in self.requests if r.due >= t0
                 and r.error is None and r.state.finish is not None]
         tasks = [t for r in done for t in r.state.task_records]
-        macs = {name: convnet.executable_macs(name, s["spatial"],
-                                              s["channels"])
-                for name, s in self.config["networks"].items()}
+        macs = {name: self.family.macs(name, shape)
+                for name, shape in self.config["networks"].items()}
         flops = sum(2.0 * macs[self.graphs[n].name]
                     for r in done for n in r.state.networks)
         traced = []
@@ -348,11 +334,21 @@ class Driver:
 
 class Control(Driver):
     """The control in the program's place: every kept output is the
-    reference computed with each convolution's operands rounded to float8
-    (e4m3), one precision below the bfloat16 the served genes run in."""
+    family's reference in its ``fp8`` mode, one precision below the
+    bfloat16 the served genes run in (for ``convnet``, each convolution's
+    operands rounded to float8 e4m3)."""
 
     def release(self) -> None:
         super().release()
         low = self.reference("fp8")
         for req in self._kept:
             req.sinks = {name: low[name] for name in req.sinks}
+
+
+def toy(cell: harness.Cell) -> None:
+    """Shrink a resolved cell in place to the size the CPU tests serve:
+    every network at its family's toy size, a rate far below the knee, one
+    warm-up request per group and a short trace."""
+    harness.family_module(cell.config).toy(cell.config)
+    cell.config["alpha_knee"] = 20.0
+    cell.traffic.update(warmup_requests=1, trace_seconds=0.3, settle_s=0.1)

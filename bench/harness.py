@@ -1,18 +1,21 @@
 """What one benchmark run is made of, found by name from ``BENCHMARK.json``.
 
 A cell (``workloads`` entry) names a configuration and a traffic mix. The
-configuration's file is given in ``configs``; the traffic mix is
-``bench/traffic/<traffic>.json`` and names its driver,
+configuration's file is given in ``configs`` and names its network family,
+``bench/families/<family>.py`` (``convnet`` where it names none); the
+traffic mix is ``bench/traffic/<traffic>.json`` and names its driver,
 ``bench/drivers/<driver>.py``; each per-layer metric is
 ``bench/metrics/<name>.py``. Adding any of these takes new files and new
 entries only.
 
 A driver module defines ``Driver(cell)`` with ``setup()``,
 ``run_window(seconds, tracer)``, ``release()``, ``check()``,
-``end_to_end()`` and ``readings()``, and ``Control(cell)``, the same run
-with the control of ``correct`` in the program's place. A metric module
-defines ``read(readings) -> float | None`` (``None``: nothing to read in
-this run).
+``end_to_end()`` and ``readings()``; ``Control(cell)``, the same run
+with the control of ``correct`` in the program's place; and ``toy(cell)``,
+which shrinks a resolved cell in place to the size the CPU tests run. A
+family module's functions are listed in ``bench/families/convnet.py``. A
+metric module defines ``read(readings) -> float | None`` (``None``:
+nothing to read in this run).
 """
 from __future__ import annotations
 
@@ -117,6 +120,15 @@ def resolve(spec: Spec, workload: str, seed: int, seconds: float,
 
 def driver_module(cell: Cell) -> ModuleType:
     return load_module(BENCH / "drivers" / f"{cell.traffic['driver']}.py")
+
+
+#: The network family of a configuration that names none.
+DEFAULT_FAMILY = "convnet"
+
+
+def family_module(config: dict) -> ModuleType:
+    name = config.get("family", DEFAULT_FAMILY)
+    return load_module(BENCH / "families" / f"{name}.py")
 
 
 def metric_module(name: str) -> ModuleType:

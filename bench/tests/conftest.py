@@ -11,24 +11,18 @@ import time  # noqa: E402
 
 import pytest  # noqa: E402
 
-#: A toy size of each driver's cell: every network at 16x16 with 4
-#: channels (the real sizes are only for the chip), a tiny GA.
+#: Peaks for the readings of a toy run on the host CPU (TPU v5e's).
 TOY_PEAKS = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
 
 
 def toy_cell(workload: str, seed: int = 2**31 + 7, seconds: float = 1.0):
+    """A cell of ``BENCHMARK.json`` at its driver's toy size (the real sizes
+    are only for the chip)."""
     import harness
 
     cell = harness.resolve(harness.Spec.load(), workload, seed, seconds,
                            False, emit=lambda s: None)
-    if cell.traffic["driver"] == "serve":
-        for shape in cell.config["networks"].values():
-            shape["spatial"], shape["channels"] = 16, 4
-        cell.config["alpha_knee"] = 20.0
-        cell.traffic["warmup_requests"] = 1
-    else:
-        cell.traffic["ga"] = {"pop_size": 6, "generations": 2}
-        cell.seconds = 0.0
+    harness.driver_module(cell).toy(cell)
     return cell
 
 

@@ -102,8 +102,10 @@ def test_program_without_the_counters_reads_nothing(name, counters,
     assert read(name, own) is None
 
 
-@pytest.mark.parametrize("workload", ["search.ar5_synth", "serve.ar5_synth"])
-def test_traced_toy_run_reads_each_metric(workload, toy, tmp_path,
+@pytest.mark.parametrize("workload,names", [
+    pytest.param("search.ar5_synth", SEARCH, id="search.ar5_synth"),
+    pytest.param("serve.ar5_synth", SERVE[:2], id="serve.ar5_synth")])
+def test_traced_toy_run_reads_each_metric(workload, names, toy, tmp_path,
                                           monkeypatch):
     """Every program metric of the cell is finite in a traced toy run on
     the host CPU (the kernel metric needs a device plane, so only the
@@ -114,12 +116,8 @@ def test_traced_toy_run_reads_each_metric(workload, toy, tmp_path,
     toy_cell, run_toy = toy
     cell = toy_cell(workload)
     cell.trace = True
-    if cell.traffic["driver"] == "serve":
-        cell.traffic["trace_seconds"] = 0.3
-        cell.traffic["settle_s"] = 0.1
     result = run_toy(cell)
     assert result["correct"], result["checks"]
-    names = SEARCH if workload.startswith("search") else SERVE[:2]
     for name in names:
         value = result["metrics"][name]["value"]
         assert math.isfinite(value) and value > 0.0, name
