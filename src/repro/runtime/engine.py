@@ -24,8 +24,12 @@ from ..spans import span
 #: Span totals of the serving path over every runtime in this process
 #: (``<span>.ns`` self time, ``<span>.n`` count): ``puzzle.serve.dispatch``
 #: (``Coordinator._dispatch``), ``puzzle.serve.stage`` (a worker's staging
-#: thread, per task) and ``puzzle.serve.execute`` (:meth:`Engine.execute`,
-#: up to ``block_until_ready``).
+#: thread, per task), ``puzzle.serve.execute`` (:meth:`Engine.execute`,
+#: up to ``block_until_ready``) and ``puzzle.serve.load`` (:meth:`Engine.load`
+#: of a subgraph not yet loaded: building, jitting and warming its program,
+#: every compile or persistent-cache load included). The counter
+#: ``puzzle.serve.load.param_bytes`` sums the weight bytes the loaded
+#: programs hold, where the executable states them (``param_bytes``).
 totals: Counter = Counter()
 
 
@@ -64,11 +68,14 @@ class Engine:
         with self._lock:
             if key not in self._handles:
                 model = executables[placed.subgraph.graph.name]
-                fn, example = model.build_subgraph_fn(
-                    placed.subgraph.layer_ids, placed.dtype
-                )
-                fn.__name__ = program_name(placed)
-                self._handles[key] = (self._prepare(fn, example), example)
+                ids = placed.subgraph.layer_ids
+                with span("puzzle.serve.load", totals):
+                    fn, example = model.build_subgraph_fn(ids, placed.dtype)
+                    fn.__name__ = program_name(placed)
+                    self._handles[key] = (self._prepare(fn, example), example)
+                if hasattr(model, "param_bytes"):
+                    totals["puzzle.serve.load.param_bytes"] += \
+                        model.param_bytes(ids, placed.dtype)
         return key
 
     def _prepare(self, fn: Callable, example: Tuple) -> Callable:

@@ -385,8 +385,8 @@ class PuzzleRuntime:
     def stats(self) -> Dict[str, Any]:
         """Operator counters: tensor pool, transport, per-worker busy time
         and tasks, and ``spans``: each serving span's count and mean self
-        time in µs (``puzzle.serve.dispatch``, ``.stage``, ``.execute``),
-        totals over every runtime in this process."""
+        time in µs (``puzzle.serve.dispatch``, ``.stage``, ``.execute``,
+        ``.load``), totals over every runtime in this process."""
         totals = dict(engine_totals)
         spans = {key[:-2]: {"n": n, "mean_us": totals[key[:-2] + ".ns"]
                             / n / 1e3}

@@ -7,21 +7,24 @@ Two faces per model:
   (backbone chain + skip/branch merges) — what the Static Analyzer
   schedules when reproducing the paper's experiments with the
   :class:`TableBackend`;
-* an **executable reduction** (:class:`ExecutableMobileModel`) — a real JAX
-  conv network with the same DAG topology, small enough to run on this
-  host's CPU in milliseconds, used by the :class:`JaxExecBackend` for
-  literal device-in-the-loop profiling and by the Runtime's engines.
+* a **synthetic executable** (:class:`ExecutableMobileModel`) — a JAX
+  conv stack with the same DAG topology at one width and one resolution,
+  used by the :class:`JaxExecBackend` for device-in-the-loop profiling and
+  by the Runtime's engines. Its size is the caller's: a few channels at
+  8-16 px in the tests, Table 6's input resolutions in the benchmark.
+
+The published architectures of yolov8n and fastsam_s, with their real
+per-layer shapes, are built by :mod:`repro.zoo.yolov8`.
 """
 from __future__ import annotations
 
-from typing import Callable, Dict, List, Sequence, Tuple
+from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
 from ..core.graph import Edge, Layer, ModelGraph
+from .executable import DagExecutable, conv2d
 from .profiles import MODEL_NAMES, MODEL_SPECS
-
-_DTYPE_BYTES = {"fp32": 4, "fp16": 2, "int8": 1}
 
 
 def _mac_profile(n: int) -> np.ndarray:
@@ -92,23 +95,21 @@ def all_cost_graphs() -> Dict[str, ModelGraph]:
 
 
 # ---------------------------------------------------------------------------
-# Executable reductions: real JAX conv nets with the same topology.
+# Synthetic executables: JAX conv stacks with the same topology.
 # ---------------------------------------------------------------------------
 
 
-class ExecutableMobileModel:
-    """A small real conv network matching a cost graph's DAG topology.
+class ExecutableMobileModel(DagExecutable):
+    """A synthetic JAX conv network matching a cost graph's DAG topology.
 
-    Layers operate on NHWC tensors of fixed spatial size; `add_merge`
-    layers consume (chain_input, skip_input). `build_subgraph_fn` returns a
-    jit-able function computing the subgraph outputs from its boundary
-    inputs — this is what the device-in-the-loop profiler times and what
-    the Runtime engines execute.
+    Every layer works on NHWC tensors of the input's shape: a 3x3 "SAME"
+    convolution at one width with ReLU, or an ``add_merge`` (ReLU of the
+    sum of its chain and skip inputs). It is the special case of
+    :class:`DagExecutable` in which every layer has the input's shape.
     """
 
     def __init__(self, name: str, channels: int = 8, spatial: int = 16, seed: int = 0):
         import jax
-        import jax.numpy as jnp
 
         self.name = name
         self.graph = make_cost_graph(name)
@@ -128,13 +129,11 @@ class ExecutableMobileModel:
                 )
         self._input = np.asarray(
             jax.random.normal(key, self.input_shape()), dtype=np.float32)
-        self._jnp = jnp
-        self._jax = jax
 
     # -- layer semantics -------------------------------------------------------
-    def _apply_layer(self, lid: int, inputs: Sequence, dtype):
-        jnp = self._jnp
+    def apply_layer(self, lid: int, inputs: Sequence, dt):
         import jax
+        import jax.numpy as jnp
 
         layer = self.graph.layers[lid]
         x = inputs[0]
@@ -143,86 +142,21 @@ class ExecutableMobileModel:
             for other in inputs[1:]:
                 out = out + other
             return jax.nn.relu(out)
-        w = jnp.asarray(self._weights[lid], dtype=dtype)
-        y = jax.lax.conv_general_dilated(
-            x, w, window_strides=(1, 1), padding="SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        )
-        return jax.nn.relu(y)
-
-    def _np_dtype(self, dtype: str):
-        jnp = self._jnp
-        return {"fp32": jnp.float32, "fp16": jnp.bfloat16, "int8": jnp.bfloat16}[dtype]
+        w = jnp.asarray(self._weights[lid], dtype=dt)
+        return jax.nn.relu(conv2d(x, w))
 
     def input_shape(self) -> Tuple[int, int, int, int]:
         return (1, self.spatial, self.spatial, self.channels)
 
     def model_input(self) -> np.ndarray:
-        """The network's input tensor (float32, seeded)."""
         return self._input
 
-    def boundary(
-        self, layer_ids: Sequence[int]
-    ) -> Tuple[List[Tuple[int, int]], List[int]]:
-        """A subgraph's interface, in :meth:`build_subgraph_fn` order.
+    def output_shape(self, lid: int) -> Tuple[int, int, int, int]:
+        return self.input_shape()
 
-        Returns its external inputs as ``(src_layer, dst_layer)`` pairs, one
-        per argument (``src_layer == -1`` is the model input), and the
-        layers whose outputs it returns: every layer with an edge leaving
-        the subgraph, and the network's sinks.
-        """
-        ids = sorted(layer_ids)
-        id_set = set(ids)
-        ext_inputs: List[Tuple[int, int]] = []
-        for lid in ids:
-            preds = [e.src for e in self.graph.in_edges[lid]]
-            if not preds:
-                ext_inputs.append((-1, lid))
-            for p in preds:
-                if p not in id_set:
-                    ext_inputs.append((p, lid))
-        out_ids = [lid for lid in ids
-                   if not self.graph.out_edges[lid]
-                   or any(e.dst not in id_set
-                          for e in self.graph.out_edges[lid])]
-        return ext_inputs, out_ids
-
-    def build_subgraph_fn(
-        self, layer_ids: Sequence[int], dtype: str = "fp32"
-    ) -> Tuple[Callable, Tuple]:
-        """(fn, example_args) computing this subgraph from boundary inputs.
-
-        ``fn`` returns the outputs of :meth:`boundary`'s output layers (one
-        array, or a tuple of several). The example of a model-input
-        argument is :meth:`model_input`; the others are filled with 0.1.
-        """
-        jnp = self._jnp
-        dt = self._np_dtype(dtype)
-        ids = sorted(layer_ids)
-        id_set = set(ids)
-        ext_inputs, out_ids = self.boundary(ids)
-
-        def fn(*args):
-            env: Dict[int, object] = {}
-            ext = {pair: a for pair, a in zip(ext_inputs, args)}
-            for lid in ids:
-                preds = [e.src for e in self.graph.in_edges[lid]]
-                ins = []
-                if not preds:
-                    ins.append(ext[(-1, lid)])
-                for p in preds:
-                    ins.append(env[p] if p in id_set else ext[(p, lid)])
-                env[lid] = self._apply_layer(lid, ins, dt)
-            outs = [env[lid] for lid in out_ids]
-            return outs[0] if len(outs) == 1 else tuple(outs)
-
-        shape = self.input_shape()
-        args = tuple(
-            jnp.asarray(self._input, dtype=dt) if src < 0
-            else jnp.zeros(shape, dtype=dt) + 0.1
-            for src, _ in ext_inputs
-        )
-        return fn, args
+    def layer_weights(self, lid: int) -> Sequence[np.ndarray]:
+        w = self._weights.get(lid)
+        return () if w is None else (w,)
 
     def reference_forward(self) -> np.ndarray:
         """The whole network in plain float32 numpy on :meth:`model_input`.

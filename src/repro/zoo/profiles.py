@@ -2,7 +2,8 @@
 
 These numbers seed the :class:`~repro.core.profiler.TableBackend` so the
 paper-faithful experiments use the paper's own device measurements — the
-honest substitute for a Galaxy S23U in this environment (DESIGN.md §2).
+honest substitute for a Galaxy S23U, which no machine of this repository
+has.
 
 Units: seconds. Keys: model name -> (processor kind, dtype, backend) -> s.
 """

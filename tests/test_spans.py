@@ -133,7 +133,7 @@ def test_program_span_names():
     assert {"puzzle.batch.tables", "puzzle.batch.lanes", "puzzle.ga.run",
             "puzzle.ga.mate", "puzzle.ga.local", "puzzle.ga.select",
             "puzzle.serve.dispatch", "puzzle.serve.stage",
-            "puzzle.serve.execute"} <= names
+            "puzzle.serve.execute", "puzzle.serve.load"} <= names
     assert all(n.startswith("puzzle.") for n in names), names
     assert not names & BENCHMARK_SPANS
 
@@ -239,6 +239,32 @@ def test_serving_spans_count_every_task(zoo):
         assert serve_totals[name + ".n"] - before[name + ".n"] == tasks
         assert stats[name]["n"] == serve_totals[name + ".n"]
         assert stats[name]["mean_us"] > 0.0
+
+
+@pytest.mark.parametrize("dtype", [0, 1], ids=["fp32", "fp16"])
+def test_load_span_counts_each_loaded_subgraph(zoo, dtype):
+    """``puzzle.serve.load`` once per subgraph a runtime loads, and
+    ``puzzle.serve.load.param_bytes`` the weight bytes of their programs at
+    the served dtype."""
+    graphs = [zoo["face_det"].graph, zoo["selfie_seg"].graph]
+    sol = _solution(graphs)
+    sol.dtype = [dtype, dtype]
+    placed = decode_solution(sol, graphs)
+    before = Counter(serve_totals)
+    with PuzzleRuntime(graphs, sol, mobile_processors(), zoo):
+        pass
+    d = Counter(serve_totals)
+    d.subtract(before)
+    subgraphs = [p for pl in placed for p in pl]
+    assert len(subgraphs) == 3
+    assert d["puzzle.serve.load.n"] == 3
+    assert d["puzzle.serve.load.ns"] > 0
+    size = 4 if dtype == 0 else 2
+    weights = sum(zoo[p.subgraph.graph.name]._weights[lid].size * size
+                  for p in subgraphs for lid in p.subgraph.layer_ids
+                  if lid in zoo[p.subgraph.graph.name]._weights)
+    assert weights > 0
+    assert d["puzzle.serve.load.param_bytes"] == weights
 
 
 @pytest.mark.parametrize("engine", [JitEngine, FastMathJitEngine],
