@@ -34,33 +34,42 @@ Fallbacks (counted and logged by :func:`repro.core.batchsim.run_batch`)
 ----------------------------------------------------------------------
 * ``collect_tasks=True`` — task-trace collection is python-side by design,
   so ``run_batch`` sends such batches to numpy without asking this core;
-* ready-queue bound — each ``(lane, pid, priority class)`` FIFO ring has
-  a fixed capacity (host-computed from the lane's task-count bound); a
-  bound above :data:`QUEUE_CAP_MAX` runs the batch on the numpy tier,
-  whose queues grow without bound (reason ``"queue-bound"``);
-* ready-queue overflow — an in-carry flag set when a ring would wrap
-  (impossible below the bound; reason ``"overflow"``);
+* ready-queue bound — the ready queues are keys in a table with one entry
+  per ``(lane, request, subgraph)``; a lane set whose requests × subgraphs
+  exceed :data:`QUEUE_PAIRS_MAX`, or whose priorities do not fit the
+  key's ``int32`` word, runs on the numpy tier, whose queues grow without
+  bound (reason ``"queue-bound"``);
 * iteration-cap guard — a generous host-computed event bound; hitting it
   (impossible by construction, like the numpy z-table bound) falls back
   rather than hanging inside XLA (reason ``"itercap"``).
 
-Each of the last three returns ``None`` with the reason in ``last_stats``;
+Each of the last two returns ``None`` with the reason in ``last_stats``;
 ``run_batch`` counts it in ``batchsim.compiled_fallbacks`` and logs a
 warning, so a run that was meant for the device never silently stays on
 the host. JAX itself is required: a failed import is an error.
 
-Ready queues: FIFO rings instead of scanned slots
--------------------------------------------------
+Ready queues: a key table instead of scanned slots
+--------------------------------------------------
 The numpy tier keeps per-``(lane, pid)`` slot arrays and scans them
-(argmin over packed ``(class, priority, release_seq)`` keys) on every pop —
-O(capacity) per event, fine when capacity stays small, ruinous inside a
-compiled loop where GA overload lanes push hundreds of entries. The
-compiled core exploits a structural property instead: ``release_seq`` is a
-per-lane monotone counter, so pushes into any single ``(class, priority)``
-bucket already arrive in key order. Pop order ``(class, priority, seq)``
-therefore reduces to "first non-empty FIFO in class order" — one dispatch-
-token FIFO (class 0) plus one FIFO per priority rank — giving O(1) pushes
-and pops with no key storage and no scans, at any capacity.
+(argmin over packed ``(class, priority, release_seq)`` keys) on every pop,
+and grows them when they fill. The compiled core uses a structural
+property instead: within a lane every ``(request, subgraph)`` pair is
+released exactly once, so a ready queue needs no slots, only one key per
+pair, and the loop already holds a ``(W, R, S)`` ``int32`` table indexed
+by that pair: ``pend``, each task's count of unfinished predecessors. A
+task waiting for its worker keeps its key there, ``priority << SEQ_BITS
+| release_seq`` (the numpy tier's order without its class: dispatch
+tokens carry no payload and live in a per-lane counter, popped first),
+stored as ``key + INT32_MIN`` so that every queued entry is negative and
+every pending count is not. All releases of one event belong to one
+request (the arriving request's roots, or the completed subgraph's
+successors, which share its request), so the event's new counts and keys
+make one row, written with one masked select. A pop is one masked min
+over the entries whose subgraph runs on the worker; keys are unique
+within a lane, so the entry equal to a negative minimum is the task
+popped, and a one-hot reduction gives back its ``(request, subgraph)``.
+Neither needs a scatter, a gather or head/tail counters. A separate key
+table compiled to 5-6 more fusions of the body on v5e.
 
 ``float64`` everywhere: calls run under ``jax.enable_x64(True)`` so
 the repo's global default (float32, required by the kernel/model stacks)
@@ -77,10 +86,11 @@ gathers) and 24 dynamic-update-slices when clean, and 240-250 fusions and
 40 dynamic-update-slices with noise, dispatch load and faults. Every read
 and write over a small static axis is therefore a one-hot select or
 masked reduction (:func:`oh_get`), which fuses with its neighbours: 156,
-174 and 182 fusions, none of them a dynamic-update-slice. A true gather
-is kept only for axes longer than :data:`ONE_HOT_MAX` (the FIFO rings'
-read, the noise and straggler tables), and a scatter only for the rings'
-push. ``tests/test_chip_compile.py`` holds the body to this.
+174 and 182 fusions, none of them a dynamic-update-slice. The ready
+queues as keys in ``pend`` then took the scatters and the gathers of FIFO
+rings out: 110, 129 and 137 fusions. A true gather is kept only for axes
+longer than :data:`ONE_HOT_MAX` (the noise and straggler tables).
+``tests/test_chip_compile.py`` holds the body to this.
 """
 from __future__ import annotations
 
@@ -103,12 +113,17 @@ from .processors import Processor
 COMPILED_REL_TOL = 1e-9
 COMPILED_ABS_TOL = 1e-12
 
-#: Hard cap on the per-(lane, pid, priority class) FIFO-ring capacity. The
-#: actual capacity is the power-of-two bucket of the lane set's exact
-#: released-task bound (``num_requests × tasks per request``), so overflow
-#: is impossible below the cap; workloads whose bound exceeds it run on the
-#: numpy tier (its queues grow without bound).
-QUEUE_CAP_MAX = 4096
+#: Largest requests × subgraphs of one lane's request group that the
+#: compiled tier accepts: the ready queues keep one key per
+#: ``(request, subgraph)`` pair, in ``pend``, so this bounds that table.
+#: A lane set above it runs on the numpy tier (its queues grow without
+#: bound).
+QUEUE_PAIRS_MAX = 4096
+
+#: Bits of a ready-queue key below the priority: the lane's release
+#: counter, which counts every task and dispatch token it releases, at
+#: most ``2 × QUEUE_PAIRS_MAX``.
+SEQ_BITS = (2 * QUEUE_PAIRS_MAX).bit_length()
 
 #: Largest per-lane axis that the lock-step loop reads as a one-hot masked
 #: reduction; a longer axis keeps a true gather. On TPU every gather is a
@@ -116,9 +131,8 @@ QUEUE_CAP_MAX = 4096
 #: pair of 32-bit words): ~0.4 µs each at 16 lanes on one v5e, while a
 #: one-hot read fuses with its neighbours (~0.2 µs per reduction) but
 #: touches W × axis elements (× K for the delivery slots). The small
-#: static axes (workers, groups, priority classes, subgraphs, requests)
-#: lie below the limit, the FIFO rings (capacity ≥ 256 per worker and
-#: class) and the noise and straggler tables (one entry per draw) above.
+#: static axes (workers, groups, subgraphs, requests) lie below the
+#: limit, the noise and straggler tables (one entry per draw) above.
 #: On one v5e a limit of 16, which reads the subgraph axis with gathers,
 #: cost 10-16% more per iteration, and one of 64, which reads the request
 #: axis with gathers, was within 2% of this one.
@@ -184,8 +198,7 @@ def _advance_factory() -> object:
 
     @partial(jax.jit, static_argnums=0)
     def advance(flags, tab):
-        (G, P, NP, CAP, any_noise, any_fault, any_strag,
-         any_dispatch) = flags
+        G, P, any_noise, any_fault, any_strag, any_dispatch = flags
         arrtab = tab["arrtab"]            # (W, G, NR)
         W, _, NR = arrtab.shape
         S = tab["exec_v"].shape[1]
@@ -226,25 +239,28 @@ def _advance_factory() -> object:
         FC = strag_tab.shape[1]
         T = thr_pid.shape[1]
         D = drop_pid.shape[1]
-        WI = jnp.arange(W)
         i64 = jnp.int64
         BIGSEQ = i64(_BIGSEQ)
         INF = jnp.float64(jnp.inf)
         M21 = i64((1 << 21) - 1)
-        HEAD = jnp.array([1, 0], i64)
-        TAIL = jnp.array([0, 1], i64)
+        QBASE = jnp.int32(np.iinfo(np.int32).min)
+        proc_of = tab["proc_of"]
+        # (request, subgraph) as one position in the pending table
+        RS = jnp.arange(R * S, dtype=jnp.int32).reshape(R, S)
 
         # --- one-hot reads and writes -----------------------------------
         # On TPU an iteration costs its number of kernels, and every
         # gather, scatter and dynamic-update-slice is one of its own (two
         # for a 64-bit array): see the module docstring. Every read and
         # write over a small static axis (frontier columns C, workers P,
-        # ring slots K, groups G, requests R, subgraphs S, priority
-        # classes) is a one-hot select or masked reduction that fuses with
-        # its neighbours (:func:`oh_get`, ``oh_set``). Only axes longer
-        # than :data:`ONE_HOT_MAX` keep a gather (the FIFO rings' read, the
-        # noise and straggler tables); the rings' push keeps its scatter,
-        # as a one-hot write would rewrite every ring.
+        # ring slots K, groups G, requests R, subgraphs S) is a one-hot
+        # select or masked reduction that fuses with its neighbours
+        # (:func:`oh_get`, ``oh_set``). Only axes longer than
+        # :data:`ONE_HOT_MAX` keep a gather (the noise and straggler
+        # tables). The ready queues are keys in ``pend`` (module
+        # docstring): a push is a masked select into one request's row, a
+        # pop a masked min over the table, so neither is a scatter or a
+        # gather.
         def oh(m, col, width):
             return m[:, None] & (jnp.asarray(col)[..., None]
                                  == jnp.arange(width))
@@ -253,11 +269,6 @@ def _advance_factory() -> object:
             o = oh(m, col, arr.shape[1])
             v = val[:, None] if getattr(val, "ndim", 0) else val
             return jnp.where(o, v, arr)
-
-        def oh2(m, i, j2, d1, d2):
-            return (m[:, None, None]
-                    & (i[:, None, None] == jnp.arange(d1)[None, :, None])
-                    & (j2[:, None, None] == jnp.arange(d2)[None, None, :]))
 
         # --- masked primitive updates ------------------------------------
         def append_deliver(st, m, pid, g, rr, t):
@@ -273,33 +284,21 @@ def _advance_factory() -> object:
             st["seq"] = st["seq"] + m
             return st
 
-        def queue_push(st, m, pid, cls, g, rr):
-            """Append to the (pid, cls) FIFO ring; O(1), order = push order
-            = release_seq order = the numpy tier's packed-key order."""
-            pid_c = jnp.clip(pid, 0, P - 1)
-            head, pos = oh_get(st["ring"], pid_c, cls).T
-            st["overflow"] = st["overflow"] | (m & (pos - head >= CAP))
-            idx = pos & (CAP - 1)
-            pid_s = jnp.where(m, pid, P)
-            st["fifo"] = st["fifo"].at[WI, pid_s, cls, idx].set(
-                ((g + 1) << 21) | (rr + 1), mode="drop")
-            st["ring"] = (st["ring"]
-                          + oh2(m, pid, cls, P, NP)[..., None] * TAIL)
-            return st
-
         def placements(gs):
             """(worker, priority) of each subgraph in ``gs`` (W, J): every
             release of an event at once, ahead of the releases."""
             pl = oh_get(place, jnp.clip(gs, 0, S - 1))
             return pl[..., 0], pl[..., 1]
 
-        def release(st, m, g, rr, t, pid, prio):
+        def release(st, row, m, g, rr, t, pid, prio):
             """Reference ``release()``: dispatch token, then the task
             (subgraph ``g`` on worker ``pid`` at priority ``prio``).
 
             Tokens carry no payload and only ever queue on the lane's
-            single ``dispatch_pid``, so the token "FIFO" is a per-lane
-            counter — no ring storage, no scatter."""
+            single ``dispatch_pid``, so the token queue is a per-lane
+            counter. A task whose worker is busy gets its key in ``row``,
+            the ``pend`` row of request ``rr`` that the body writes once
+            every release of the event is in it."""
             neg1 = jnp.full((W,), -1, i64)
             if any_dispatch:
                 dm = m & dispatch_known
@@ -311,38 +310,39 @@ def _advance_factory() -> object:
             st["rel_seq"] = st["rel_seq"] + m
             is_idle = oh_get(st["idle"], pid)
             st = append_deliver(st, m & is_idle, pid, g, rr, t)
-            st = queue_push(st, m & ~is_idle, pid, prio, g, rr)
-            return st
+            q = m & ~is_idle
+            st["queued"] = st["queued"] + q
+            key = ((prio << SEQ_BITS) | st["rel_seq"]).astype(jnp.int32)
+            return st, oh_set(row, q, g, key + QBASE)
 
         def pull_next(st, m, pid, t):
             """Pop the earliest-keyed entry: queued dispatch tokens first
-            (class 0), else the head of the first non-empty priority
-            FIFO."""
-            pid_c = jnp.clip(pid, 0, P - 1)
+            (class 0), else the least key among the queued tasks whose
+            subgraph runs on ``pid``. Keys are unique within a lane, so
+            the entry equal to that minimum is the one popped."""
             if any_dispatch:
                 tok_has = m & (pid == dispatch_pid) & (st["tok"] > 0)
                 st["tok"] = st["tok"] - tok_has
             else:
                 tok_has = jnp.zeros((W,), bool)
-            heads, tails = jnp.moveaxis(oh_get(st["ring"], pid_c), -1, 0)
-            nonempty = heads < tails
-            sel = jnp.argmax(nonempty, axis=1)           # first non-empty
-            fifo_has = m & ~tok_has & jnp.any(nonempty, axis=1)
-            idx = oh_get(heads, sel) & (CAP - 1)
-            v = oh_get(st["fifo"], pid_c, sel, idx)
-            g = jnp.where(tok_has, -1, ((v >> 21) & M21) - 1)
-            rr = jnp.where(tok_has, -1, (v & M21) - 1)
-            st["ring"] = (st["ring"]
-                          + oh2(fifo_has, pid, sel, P, NP)[..., None] * HEAD)
-            has = tok_has | fifo_has
+            pend = st["pend"]
+            mine = (proc_of == pid[:, None])[:, None, :]
+            kmin = jnp.min(jnp.where(mine, pend, 0), axis=(1, 2))
+            task_has = m & ~tok_has & (kmin < 0)
+            hot = (pend == kmin[:, None, None]) & task_has[:, None, None]
+            at = jnp.sum(jnp.where(hot, RS, 0), axis=(1, 2),
+                         dtype=jnp.int32).astype(i64)
+            g = jnp.where(tok_has, -1, at % S)
+            rr = jnp.where(tok_has, -1, at // S)
+            st["pend"] = jnp.where(hot, 0, pend)
+            has = tok_has | task_has
             st = append_deliver(st, has, pid, g, rr, t)
             st["idle"] = st["idle"] | oh(m & ~has, pid, P)
             return st
 
         def cond(st):
             tmin = jnp.min(st["times"], axis=1)
-            return ((st["it"] < itercap) & ~jnp.any(st["overflow"])
-                    & jnp.any(tmin <= horizon))
+            return (st["it"] < itercap) & jnp.any(tmin <= horizon)
 
         def body(st):
             tmin = jnp.min(st["times"], axis=1)
@@ -368,13 +368,14 @@ def _advance_factory() -> object:
             rr = gid * NR + rid
             o_a = oh(arr_m, rr, R)
             st["arrival"] = jnp.where(o_a, t[:, None], st["arrival"])
-            st["pend"] = jnp.where(o_a[:, :, None], dep_cnt[:, None, :],
-                                   st["pend"])
             rrow = oh_get(roots, gid)                    # (W, 1 + jmax)
             pids, prios = placements(rrow[:, 1:])
+            row = dep_cnt
             for j in range(jmax):
-                st = release(st, arr_m & (j < rrow[:, 0]), rrow[:, 1 + j],
-                             rr, t, pids[:, j], prios[:, j])
+                st, row = release(st, row, arr_m & (j < rrow[:, 0]),
+                                   rrow[:, 1 + j], rr, t, pids[:, j],
+                                   prios[:, j])
+            rq = rr
             nrid = rid + 1
             has = arr_m & (nrid < nr)
             arr_next = oh_get(arow, jnp.minimum(nrid, NR - 1))
@@ -413,12 +414,17 @@ def _advance_factory() -> object:
             pjs = oh_get(prow, sjs) - jnp.sum(same, axis=2, dtype=prow.dtype)
             dec = mjs[:, :, None] & (sjs[:, :, None] == jnp.arange(S))
             prow = prow - jnp.sum(dec, axis=1, dtype=prow.dtype)
-            st["pend"] = jnp.where(o_r[:, :, None], prow[:, None, :],
-                                   st["pend"])
+            row = jnp.where(mA[:, None], row, prow)
             pids, prios = placements(sjs)
             for j in range(dmax):
-                st = release(st, mjs[:, j] & (pjs[:, j] == 0), sjs[:, j], rr,
-                             t, pids[:, j], prios[:, j])
+                st, row = release(st, row, mjs[:, j] & (pjs[:, j] == 0),
+                                  sjs[:, j], rr, t, pids[:, j], prios[:, j])
+            # one row write for the event: the arriving request's fresh
+            # counts, or the completed task's request with its successors'
+            # decrements, each with the keys of its queued releases
+            rq = jnp.where(mA, rq, rr_c)
+            st["pend"] = jnp.where(oh(arr_m | real, rq, R)[:, :, None],
+                                   row[:, None, :], st["pend"])
             st["times"] = oh_set(st["times"], mC, G + pid, INF)
             st["seqs"] = oh_set(st["seqs"], mC, G + pid, BIGSEQ)
             st["end_g"] = oh_set(st["end_g"], mC, pid, i64(-2))
@@ -552,28 +558,25 @@ def _advance_factory() -> object:
             "done": jnp.zeros((W, R), i64),
             "pend": jnp.zeros((W, R, S), jnp.int32),
             "busy": jnp.zeros((W, P)),
-            "fifo": jnp.zeros((W, P, NP, CAP), i64),
-            "ring": jnp.zeros((W, P, NP, 2), i64),     # (head, tail)
             "tok": jnp.zeros((W,), i64),
             "del_pack": jnp.zeros((W, K), i64),
             "del_n": jnp.zeros((W,), i64),
             "zpos": jnp.zeros((W,), i64),
             "fpos": jnp.zeros((W,), i64),
-            "overflow": jnp.zeros((W,), bool),
             "events": jnp.zeros((W,), jnp.int32),
+            "queued": jnp.zeros((W,), jnp.int32),
             "it": jnp.zeros((), i64),
         }
         out = lax.while_loop(cond, body, st0)
         return (out["arrival"], out["first_start"], out["last_finish"],
-                out["done"], out["busy"], jnp.any(out["overflow"]),
-                out["events"],
+                out["done"], out["busy"], out["events"], out["queued"],
                 out["it"])
 
     return advance
 
 
 #: Diagnostics of the most recent :func:`run_batch_compiled` call:
-#: ``{"iters", "itercap", "overflow", "fallback"}``, plus ``"reason"`` on a
+#: ``{"iters", "itercap", "fallback"}``, plus ``"reason"`` on a
 #: fallback. Tests and the simspeed benchmark read this to tell a compiled
 #: run from a fallback.
 last_stats: dict = {}
@@ -585,7 +588,9 @@ last_stats: dict = {}
 #: which the lane was active (one event each: an arrival, a deferred first
 #: arrival, a completion or dispatch token, or a delivery drain), and
 #: ``lane_slots`` is iterations × padded width, the lane-iterations the
-#: device computed. The spans ``puzzle.batch.tables`` (host lane tables,
+#: device computed. ``queued`` sums, over real lanes, the task releases
+#: that found their worker busy and waited in the ready queues.
+#: The spans ``puzzle.batch.tables`` (host lane tables,
 #: :func:`build_tables`) and ``puzzle.batch.lanes`` (lane assembly in the
 #: analyzer's batch entry points) add ``<span>.ns`` / ``<span>.n`` here.
 totals: Counter = Counter()
@@ -626,7 +631,8 @@ def build_tables(
     processors: Sequence[Processor],
 ) -> Optional[LaneTables]:
     """Precompute a batch's lane tables on the host; ``None`` when the
-    lanes' ready-queue bound exceeds :data:`QUEUE_CAP_MAX`.
+    lanes' ready queues exceed :data:`QUEUE_PAIRS_MAX` pairs per request
+    group, or their priorities the key's ``int32`` word.
 
     Arrival tables, noise multipliers and straggler multipliers use the
     scalar engines' exact expressions; every array is padded to its shape
@@ -742,18 +748,15 @@ def build_tables(
     idle0 = np.zeros(P, bool)
     idle0[pids] = True
 
-    # FIFO classes: one per priority rank (dispatch tokens live in a
-    # per-lane counter, not a ring). Ring capacity = exact bound on entries
-    # ever pushed per (lane, pid, class): every push is a released task,
-    # bounded by the lane's total task count across all requests.
-    NP = int(prio_of.max()) + 1
-    qbound = int((nr * group_tasks.sum(axis=1)).max())
-    CAP = _bucket(qbound + 4)
-    if CAP > QUEUE_CAP_MAX:
+    # ready queues: one key per (request, subgraph) pair of a request
+    # group, priority << SEQ_BITS | release count, in an int32 word
+    releases = 2 * int((nr * group_tasks.sum(axis=1)).max())  # and tokens
+    if (nr_max * S > QUEUE_PAIRS_MAX or releases >= 1 << SEQ_BITS
+            or int(prio_of.max()) >= 1 << (31 - SEQ_BITS)):
         return None
 
     # generous per-lane event bound: arrivals + completions (tasks +
-    # dispatch tokens) + ring-head pops, doubled. Hitting it means a bug;
+    # dispatch tokens) + queue pops, doubled. Hitting it means a bug;
     # the caller falls back to numpy instead of hanging.
     task_max = int(group_tasks.sum(axis=1).max())
     itercap = 64 + 2 * (G * (nr_max + 2) + 4 * nr_max * task_max)
@@ -826,7 +829,7 @@ def build_tables(
         "idle0": idle0,
         "itercap": np.int64(itercap),
     }
-    flags = (G, P, NP, CAP, any_noise, any_fault, any_strag, any_dispatch)
+    flags = (G, P, any_noise, any_fault, any_strag, any_dispatch)
     return LaneTables(
         flags=flags, tab=tab, lanes=lanes, groups=groups, pids=pids, nr=nr,
         horizon=horizon, group_tasks=group_tasks, nr_bucket=NRB,
@@ -843,9 +846,9 @@ def run_batch_compiled(
     Builds the lane tables on the host (:func:`build_tables`), then the
     jitted loop advances the shared frontier to quiescence. Returns a
     :class:`repro.core.batchsim.BatchResult` (``tasks=None``), or ``None``
-    with ``last_stats["reason"]`` set when the queue bound exceeds
-    :data:`QUEUE_CAP_MAX`, a ring overflowed, or the iteration guard
-    tripped — the caller reruns on the bit-exact numpy tier then.
+    with ``last_stats["reason"]`` set when the ready queues exceed their
+    bound (:func:`build_tables`) or the iteration guard tripped — the
+    caller reruns on the bit-exact numpy tier then.
     """
     import jax
 
@@ -855,24 +858,23 @@ def run_batch_compiled(
     with span("puzzle.batch.tables", totals):
         t = build_tables(lanes, groups, processors)
     if t is None:
-        last_stats.update(fallback=True, overflow=False, iters=0,
-                          itercap=0, reason="queue-bound")
+        last_stats.update(fallback=True, iters=0, itercap=0,
+                          reason="queue-bound")
         return None
     W = len(t.lanes)
     with jax.enable_x64(True):
         jtab = {k: jax.numpy.asarray(v) for k, v in t.tab.items()}
-        (arrival, first_start, last_finish, done, busy, overflow, events,
+        (arrival, first_start, last_finish, done, busy, events, queued,
          iters) = advance_fn()(t.flags, jtab)
-        overflow = bool(overflow)
         iters = int(iters)
-        fallback = overflow or iters >= t.itercap
-        last_stats.update(iters=iters, itercap=t.itercap, overflow=overflow,
-                          fallback=fallback)
+        fallback = iters >= t.itercap
+        last_stats.update(iters=iters, itercap=t.itercap, fallback=fallback)
         totals.update(calls=1, iters=iters, itercap=t.itercap,
                       lane_events=int(np.asarray(events)[:W].sum()),
+                      queued=int(np.asarray(queued)[:W].sum()),
                       lane_slots=iters * len(t.tab["horizon"]))
         if fallback:
-            last_stats["reason"] = "overflow" if overflow else "itercap"
+            last_stats["reason"] = "itercap"
             return None
         arrival = np.asarray(arrival)[:W]
         first_start = np.asarray(first_start)[:W]

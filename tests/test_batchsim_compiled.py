@@ -31,6 +31,7 @@ from repro.core import (
     PAPER_COMM_MODEL,
     SolutionFactory,
     build_spec,
+    chain_graph,
     decode_solution,
     run_batch,
     run_batch_compiled,
@@ -45,6 +46,7 @@ from test_batchsim_properties import (
 from test_golden_traces import (
     GOLDEN_DIR,
     SCENARIOS,
+    _nets_tri_chain,
     _solution,
 )
 from test_golden_traces import PROCS as GPROCS
@@ -201,10 +203,8 @@ def test_compiled_differential_faults(seed):
     _compare_three_tiers(f"faults-{seed}", lanes, groups)
 
 
-def test_compiled_overload_inf_parity():
-    """Deep-queue overload: dropped requests (inf makespans) and partial
-    done counts agree with the numpy tier — the FIFO rings must not
-    overflow at the host-computed capacity bound."""
+def _overload_lanes():
+    """Six random candidates at ~100x the arrival rate: deep queues."""
     rng = random.Random(99)
     nets, groups, periods = _random_problem(rng)
     periods = tuple(p * 0.01 for p in periods)  # ~100x arrival rate
@@ -217,6 +217,14 @@ def test_compiled_overload_inf_parity():
                           PAPER_COMM_MODEL)
         lanes.append(BatchLane(spec=spec, periods=periods, num_requests=20,
                                dispatch_overhead=150e-6))
+    return lanes, groups
+
+
+def test_compiled_overload_inf_parity():
+    """Deep-queue overload: dropped requests (inf makespans) and partial
+    done counts agree with the numpy tier — the ready queues must hold
+    every release at the host-computed bound."""
+    lanes, groups = _overload_lanes()
     ref = BatchSimulator(lanes, groups, PROCS).run()
     comp = run_batch_compiled(lanes, groups, PROCS)
     assert comp is not None
@@ -226,6 +234,93 @@ def test_compiled_overload_inf_parity():
         _assert_lane_close(ref.result(i), comp.result(i), ("overload", i))
         dropped += sum(math.isinf(m) for m in ref.makespans(i))
     assert dropped, "overload scenario dropped no requests"
+
+
+# -- ready queues: pop order and the queued counter ------------------------
+
+
+def _contended_lanes(n_lanes):
+    """Three chains of one request group, every layer its own subgraph on
+    one worker, each lane with its own network priorities: requests
+    arrive faster than the worker serves them, and the worker's dispatch
+    tokens queue beside its tasks."""
+    nets = _nets_tri_chain()
+    lanes, sols = [], []
+    for i in range(n_lanes):
+        pin = i % 2
+        sol = _solution(nets, seed=40 + i, pin=pin)
+        spec = build_spec(decode_solution(sol, nets), GPROCS, GPROFILER,
+                          PAPER_COMM_MODEL)
+        lanes.append(BatchLane(spec=spec, periods=[0.004], num_requests=4,
+                               dispatch_overhead=150e-6, dispatch_pid=pin))
+        sols.append(sol)
+    return sols, lanes, [[0, 1, 2]]
+
+
+def test_pop_order_priority_then_release():
+    """A worker that, while busy, receives releases of three priority
+    classes from several requests, with dispatch tokens queued on it,
+    serves them in the numpy tier's order: tokens, then priority, then
+    release order. Checked as task records first (the lanes do contend
+    like that), then lane for lane."""
+    sols, lanes, groups = _contended_lanes(4)
+    ref = BatchSimulator(lanes, groups, GPROCS).run(collect_tasks=True)
+    for i, sol in enumerate(sols):
+        waited = [r for r in ref.tasks[i] if r.started > r.released]
+        assert len({sol.priority.index(r.network) for r in waited}) >= 3
+        assert len({r.request for r in waited}) >= 2
+        assert len({r.processor for r in waited}) == 1
+    comp = run_batch_compiled(lanes, groups, GPROCS)
+    assert comp is not None
+    assert bsc.last_stats["fallback"] is False, bsc.last_stats
+    for i in range(len(lanes)):
+        _assert_lane_close(ref.result(i), comp.result(i), ("pop", i))
+        for r in range(lanes[i].num_requests):
+            assert ref.done[i, r] == comp.done[i, r], ("done", i, r)
+        for pid in ref.pids:
+            assert _close(ref.busy[i, pid], comp.busy[i, pid]), ("busy", i)
+
+
+@pytest.mark.parametrize("measured,arrivals_on,faults_on",
+                         [(False, False, False), (True, True, True)],
+                         ids=["clean", "measured_faults"])
+def test_queued_matches_lanes_run_alone(measured, arrivals_on, faults_on):
+    """``totals["queued"]`` counts a batch's releases that waited for
+    their worker: the sum of what its lanes count run alone."""
+    lanes, groups = _make_lanes(random.Random(8), 5, measured, arrivals_on,
+                                faults_on)
+    alone = []
+    for ln in lanes:
+        before = bsc.totals["queued"]
+        assert run_batch_compiled([ln], groups, PROCS) is not None
+        alone.append(bsc.totals["queued"] - before)
+    before = bsc.totals["queued"]
+    assert run_batch_compiled(lanes, groups, PROCS) is not None
+    assert bsc.totals["queued"] - before == sum(alone)
+
+
+def test_queued_zero_without_contention():
+    """One chain, one request at a time: no release ever finds its
+    worker busy, so nothing is queued."""
+    nets = [chain_graph("solo", [("conv", 4e6, 1000, 4000)] * 5)]
+    sol = _solution(nets, seed=3)
+    spec = build_spec(decode_solution(sol, nets), GPROCS, GPROFILER,
+                      PAPER_COMM_MODEL)
+    lane = BatchLane(spec=spec, periods=[1.0], num_requests=3)
+    before = bsc.totals["queued"]
+    comp = run_batch_compiled([lane], [[0]], GPROCS)
+    assert comp is not None
+    assert all(r.done_tasks == r.total_tasks for r in comp.result(0).requests)
+    assert bsc.totals["queued"] == before
+
+
+def test_queued_counts_overload():
+    """Every lane of the overload scenario queues releases."""
+    lanes, groups = _overload_lanes()
+    for ln in lanes:
+        before = bsc.totals["queued"]
+        assert run_batch_compiled([ln], groups, PROCS) is not None
+        assert bsc.totals["queued"] > before
 
 
 # -- fallback contract -----------------------------------------------------
@@ -246,8 +341,8 @@ def test_run_batch_compiled_collect_tasks_falls_back_bitexact():
 
 
 def test_run_batch_compiled_queue_bound_fallback():
-    """A workload whose released-task bound exceeds QUEUE_CAP_MAX is
-    declined before compilation; run_batch reruns it on numpy."""
+    """A workload whose ready queues exceed QUEUE_PAIRS_MAX is declined
+    before compilation; run_batch reruns it on numpy."""
     rng = random.Random(32)
     lanes, groups = _make_lanes(rng, 2, measured=False, arrivals_on=False,
                                 faults_on=False)
